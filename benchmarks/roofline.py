@@ -130,7 +130,7 @@ KERNEL_GEOM = dict(
 # Latency-equivalent cost of issuing ONE block-table DMA descriptor,
 # expressed in HBM bytes (descriptor setup + first-beat latency at ~1
 # GHz x ~1 TB/s).  The split pool pays this PER K AND PER V fetch; the
-# fused pool's channel-pair rows pay it once.
+# fused pool's per-head [2, bs, hd] K/V page pair pays it once.
 DMA_OVERHEAD_BYTES = 1024
 
 
@@ -147,7 +147,7 @@ def _kernel_variant_row(kernel: str, layout: str, buffering: str) -> Dict:
         * g["dtype_bytes"]
     payload = kv_payload + qo_payload
     # descriptor count: split issues separate K and V copies per
-    # (seq/row, kv head, page); fused fetches the interleaved pair once
+    # (seq/row, kv head, page); fused fetches the head's K/V pair once
     n_dma = n_rows * (2 if layout == "split" else 1)
     modeled_bytes = payload + n_dma * DMA_OVERHEAD_BYTES
     # time: DMA stream vs flash compute; multi-buffering overlaps them

@@ -30,6 +30,8 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

@@ -66,6 +66,9 @@ def main():
             "XLA_FLAGS",
             f"--xla_force_host_platform_device_count={args.pp * args.tp}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax
 
     from repro.configs import get_config

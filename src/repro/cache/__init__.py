@@ -14,9 +14,10 @@ request only ever holds ``ceil(context / block_size)`` blocks.
 
 Memory model
 ------------
-* The pool is ``[n_blocks, block_size, n_kv_heads, head_dim]`` per layer;
-  every layer shares ONE block table per request (vLLM's layout), so the
-  :class:`BlockManager` does its bookkeeping once for the whole model.
+* The pool is ``[n_blocks, n_kv_heads, 2, block_size, head_dim]`` per
+  layer (K and V fused on the pair axis); every layer shares ONE block
+  table per request (vLLM's layout), so the :class:`BlockManager` does
+  its bookkeeping once for the whole model.
 * Physical block **0 is reserved as the scratch block**: padded batch
   entries (the no-chunk iteration, unused decode lanes) point their whole
   block table at it, so their writes land somewhere harmless — this

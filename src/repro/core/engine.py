@@ -330,7 +330,9 @@ class Engine:
         # cache (arg 2) is donated: the KV/state buffers update in place
         self._step = jax.jit(self._step_impl, donate_argnums=(2,))
         self._seed_cross = jax.jit(self.model.seed_cross_kv)
-        self._reset_slot = jax.jit(_reset_slot)
+        # donated too: the block pool passes through untouched, and without
+        # donation every admission would copy the whole pool
+        self._reset_slot = jax.jit(_reset_slot, donate_argnums=(0,))
         self._cow_blocks = jax.jit(_copy_blocks, donate_argnums=(0,))
         # host KV swap tier: the numpy arena mirroring the pool leaves is
         # built lazily on the first swap (shape [.., n_host_slots, ..] per
@@ -340,6 +342,10 @@ class Engine:
         self._gather_pool = jax.jit(_gather_pool)
         self._scatter_pool = jax.jit(_scatter_pool, donate_argnums=(0,))
         self.iterations = 0
+        # [1, V] logits of the last packed step's final valid chunk row
+        # (what the sampler saw; None after a decode-only step) — left on
+        # device, read only by correctness checks against a plain forward
+        self.chunk_logits = None
 
     def _init_sp(self, sp: bool, mesh):
         """Resolve the sequence-parallel configuration: the activation
@@ -581,7 +587,7 @@ class Engine:
         # static slice; no-op when the lanes are unpadded)
         dec_tok = (sample(decode_logits[:self.D], kd, self.sampling)
                    if decode_logits is not None else None)
-        return chunk_tok, dec_tok, cache
+        return chunk_tok, dec_tok, chunk_logits, cache
 
     def execute(self, plan: IterationPlan) -> Dict[int, int]:
         """Run one iteration; returns {req_id: newly sampled token} for the
@@ -714,7 +720,7 @@ class Engine:
         # engine never traces under another engine's stale sharding)
         from repro.models import stack as _stack
         _stack.set_packed_sp_sharding(self._sp_sharding)
-        chunk_tok, dec_tok, self.cache = self._step(
+        chunk_tok, dec_tok, self.chunk_logits, self.cache = self._step(
             self.params, pk, self.cache, sub)
         self.iterations += 1
         return self._collect(chunk, decodes, chunk_tok, dec_tok)

@@ -5,10 +5,12 @@ epilogue) is identical across the decode, chunked-prefill and paged
 kernels, so it lives here once and every kernel body composes it with its
 own masking and block-fetch logic.
 
-On a TPU backend the kernels compile natively; everywhere else they run in
-``interpret=True`` mode (the kernel body executed op-by-op on CPU), which is
-how this container validates them against the ``ref.py`` oracles.
+On a TPU backend the kernels compile natively; on any other backend they
+run in ``interpret=True`` mode (the kernel body executed op-by-op), which
+is how the CPU test suite validates them against the ``ref.py`` oracles.
 ``REPRO_PALLAS_INTERPRET=0|1`` overrides that platform default either way.
+The backend query is not guarded: a JAX that cannot start its backend
+raises here rather than quietly switching the kernels to interpret mode.
 
 The fused-paged kernels' tile knobs are env-tunable:
 
@@ -31,13 +33,6 @@ from repro import env
 NEG = -1e30
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def resolve_interpret() -> bool:
     """Interpret-vs-compile for the Pallas kernels: compiled natively on a
     TPU backend, interpreted elsewhere (CPU CI), with
@@ -47,7 +42,7 @@ def resolve_interpret() -> bool:
         return False
     if v in ("1", "true"):
         return True
-    return not _on_tpu()
+    return jax.default_backend() != "tpu"
 
 
 def paged_kv_pages() -> int:

@@ -2,12 +2,13 @@
 
 The SARATHI offset-causal chunk kernel (see
 :mod:`repro.kernels.chunked_prefill_attention`) with the KV cache pooled
-into ONE head-interleaved ``[n_blocks, block_size, 2 * nk, hd]`` tensor
-and the chunk's request addressed through its block table.  As in
+into ONE fused ``[n_blocks, nk, 2, block_size, hd]`` tensor and the
+chunk's request addressed through its block table.  As in
 :mod:`repro.kernels.paged_decode_attention`, the pool stays in ``ANY``
 memory and the kernel drives its own DMAs: per grid step it copies
-``kv_pages`` physical blocks' ``[bs, 2, hd]`` K/V channel pair for the
-current head — one transfer each where the split-pool layout needed two —
+``kv_pages`` physical blocks' ``[2, bs, hd]`` K/V page pair for the
+current head — one transfer each where the split-pool layout needed two,
+cut along major axes only —
 into an ``n_buffers``-slot VMEM ring, prefetched ahead of the flash
 update so fetch overlaps compute.
 
@@ -46,7 +47,7 @@ def _kernel(start_ref, bt_ref, q_ref, pool_ref, o_ref, m_ref, l_ref,
     def _copy(slot, step, p):
         t = jnp.minimum(step * kv_pages + p, n_entries - 1)
         return pltpu.make_async_copy(
-            pool_ref.at[bt_ref[t], :, pl.ds(2 * (h // g), 2), :],
+            pool_ref.at[bt_ref[t], h // g],
             buf_ref.at[slot, p], sem_ref.at[slot, p])
 
     def _start(slot, step):
@@ -73,8 +74,8 @@ def _kernel(start_ref, bt_ref, q_ref, pool_ref, o_ref, m_ref, l_ref,
     qpos = start + i * bq + \
         jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
     for p in range(kv_pages):
-        k = buf_ref[slot, p, :, 0, :]               # [bs, hd]
-        v = buf_ref[slot, p, :, 1, :]
+        k = buf_ref[slot, p, 0]                     # [bs, hd]
+        v = buf_ref[slot, p, 1]
         s = flash_scores(q, k, scale)               # [bq, bs]
         kpos = (j * kv_pages + p) * bs + \
             jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
@@ -91,7 +92,7 @@ def paged_chunked_prefill_attention(q, pool_kv, block_table, start, *,
                                     n_buffers: Optional[int] = None,
                                     interpret: Optional[bool] = None):
     """q [C, nq, hd] — the prefill chunk's queries (positions start+i);
-    pool_kv [n_blocks, block_size, 2 * nk, hd] — the fused paged pool
+    pool_kv [n_blocks, nk, 2, block_size, hd] — the fused paged pool
     (the chunk's own KV already written through the table); block_table
     [M] int32 physical block ids (scratch-padded); start — scalar int32.
     Returns [C, nq, hd].  C must tile by bq; knobs default from
@@ -101,8 +102,7 @@ def paged_chunked_prefill_attention(q, pool_kv, block_table, start, *,
     n_buffers = paged_n_buffers() if n_buffers is None else n_buffers
     interpret = resolve_interpret() if interpret is None else interpret
     C, nq, hd = q.shape
-    bs, nch = pool_kv.shape[1], pool_kv.shape[2]
-    nk = nch // 2
+    nk, bs = pool_kv.shape[1], pool_kv.shape[3]
     M = block_table.shape[0]
     kv_pages = max(1, min(kv_pages, M))
     bq = min(bq, C)
@@ -119,7 +119,7 @@ def paged_chunked_prefill_attention(q, pool_kv, block_table, start, *,
         in_specs=[
             pl.BlockSpec((1, bq, hd),
                          lambda h, i, j, s_ref, bt_ref: (h, i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # pool: kernel-side DMA
+            pl.BlockSpec(memory_space=pl.ANY),      # pool: kernel-side DMA
         ],
         out_specs=pl.BlockSpec((1, bq, hd),
                                lambda h, i, j, s_ref, bt_ref: (h, i, 0)),
@@ -127,7 +127,7 @@ def paged_chunked_prefill_attention(q, pool_kv, block_table, start, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
-            pltpu.VMEM((n_buffers, kv_pages, bs, 2, hd), pool_kv.dtype),
+            pltpu.VMEM((n_buffers, kv_pages, 2, bs, hd), pool_kv.dtype),
             pltpu.SemaphoreType.DMA((n_buffers, kv_pages)),
         ],
     )

@@ -2,15 +2,15 @@
 
 Same computation as :mod:`repro.kernels.decode_attention` — one new query
 token per sequence attends its cached context — but the KV cache is ONE
-pooled ``[n_blocks, block_size, 2 * nk, hd]`` tensor with K/V
-head-interleaved (K head ``h`` at channel ``2h``, its V at ``2h + 1``) and
-each sequence's context lives in the physical blocks named by its block
-table.
+pooled ``[n_blocks, nk, 2, block_size, hd]`` tensor (K at pair index 0,
+V at 1) and each sequence's context lives in the physical blocks named by
+its block table.
 
 The pool stays in ``ANY`` memory (HBM) and the kernel issues its own
 block-table DMAs: per grid step it fetches ``kv_pages`` physical blocks'
-``[bs, 2, hd]`` channel pair for the current head — ONE async copy per
-page instead of the two a split-pool layout needs — into an
+``[2, bs, hd]`` K/V page pair for the current head — ONE async copy per
+page instead of the two a split-pool layout needs, slicing only the major
+(block, head) axes so every copy moves whole ``[bs, hd]`` tiles — into an
 ``n_buffers``-slot VMEM scratch ring.  With ``n_buffers > 1`` the next
 step's page fetches are started before the current step's flash-softmax
 runs, so DMA overlaps compute (the split-pool predecessor let the implicit
@@ -49,10 +49,10 @@ def _kernel(ctx_ref, bt_ref, q_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref,
 
     def _copy(slot, step, p):
         # page p of `step`: physical block bt[b, t] (clamped tail pages
-        # re-fetch the last entry; masked below), head h's channel pair
+        # re-fetch the last entry; masked below), head h's K/V page pair
         t = jnp.minimum(step * kv_pages + p, n_entries - 1)
         return pltpu.make_async_copy(
-            pool_ref.at[bt_ref[b, t], :, pl.ds(2 * h, 2), :],
+            pool_ref.at[bt_ref[b, t], h],
             buf_ref.at[slot, p], sem_ref.at[slot, p])
 
     def _start(slot, step):
@@ -80,8 +80,8 @@ def _kernel(ctx_ref, bt_ref, q_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref,
     ctx = ctx_ref[b]
     q = q_ref[0, 0]                                 # [g, hd]
     for p in range(kv_pages):
-        k = buf_ref[slot, p, :, 0, :]               # [bs, hd]
-        v = buf_ref[slot, p, :, 1, :]
+        k = buf_ref[slot, p, 0]                     # [bs, hd]
+        v = buf_ref[slot, p, 1]
         s = flash_scores(q, k, scale)               # [g, bs]
         kpos = (j * kv_pages + p) * bs + \
             jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -96,9 +96,9 @@ def paged_decode_attention(q, pool_kv, block_tables, ctx, *,
                            kv_pages: Optional[int] = None,
                            n_buffers: Optional[int] = None,
                            interpret: Optional[bool] = None):
-    """q [B, nq, hd] (ONE new token per sequence); pool_kv [n_blocks,
-    block_size, 2 * nk, hd] head-interleaved (new KV already written at
-    logical position ctx); block_tables [B, M] int32 physical block ids
+    """q [B, nq, hd] (ONE new token per sequence); pool_kv [n_blocks, nk,
+    2, block_size, hd] fused (new KV already written at logical position
+    ctx); block_tables [B, M] int32 physical block ids
     (scratch-padded); ctx [B] int32.  Returns [B, nq, hd].
 
     kv_pages — physical blocks fetched + folded per grid step;
@@ -109,8 +109,7 @@ def paged_decode_attention(q, pool_kv, block_tables, ctx, *,
     n_buffers = paged_n_buffers() if n_buffers is None else n_buffers
     interpret = resolve_interpret() if interpret is None else interpret
     B, nq, hd = q.shape
-    bs, nch = pool_kv.shape[1], pool_kv.shape[2]
-    nk = nch // 2
+    nk, bs = pool_kv.shape[1], pool_kv.shape[3]
     M = block_tables.shape[1]
     kv_pages = max(1, min(kv_pages, M))
     g = nq // nk
@@ -124,7 +123,7 @@ def paged_decode_attention(q, pool_kv, block_tables, ctx, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd),
                          lambda b, h, j, c_ref, bt_ref: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # pool: kernel-side DMA
+            pl.BlockSpec(memory_space=pl.ANY),      # pool: kernel-side DMA
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda b, h, j, c_ref, bt_ref: (b, h, 0, 0)),
@@ -132,7 +131,7 @@ def paged_decode_attention(q, pool_kv, block_tables, ctx, *,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((n_buffers, kv_pages, bs, 2, hd), pool_kv.dtype),
+            pltpu.VMEM((n_buffers, kv_pages, 2, bs, hd), pool_kv.dtype),
             pltpu.SemaphoreType.DMA((n_buffers, kv_pages)),
         ],
     )

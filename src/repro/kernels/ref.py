@@ -24,30 +24,31 @@ def decode_attention_ref(q, k, v, ctx):
 
 
 def gather_paged_rows(pool, block_tables):
-    """Reconstruct dense cache rows from a paged pool: pool [N, bs, ch, hd],
-    block_tables [..., M] -> [..., M * bs, ch, hd] (logical position order).
-    This is the oracle's view of block-table indirection — the paged
-    kernels must behave as if attending these gathered rows."""
+    """Reconstruct dense cache rows from the fused paged pool: pool
+    [N, nk, 2, bs, hd], block_tables [..., M] -> [..., M * bs, nk, 2, hd]
+    (logical position order).  This is the oracle's view of block-table
+    indirection — the paged kernels must behave as if attending these
+    gathered rows."""
     return cm.gather_block_rows(pool, block_tables)
 
 
 def fuse_kv_pools(pool_k, pool_v):
-    """Split k/v pools [N, bs, nk, hd] -> one head-interleaved fused pool
-    [N, bs, 2 * nk, hd] (the layout the paged kernels consume)."""
-    return cm.interleave_kv(pool_k, pool_v)
+    """Split k/v pools [N, bs, nk, hd] -> one fused pool [N, nk, 2, bs, hd]
+    (the layout the paged kernels consume)."""
+    return jnp.moveaxis(cm.fuse_kv(pool_k, pool_v), 1, 3)
 
 
 def paged_chunked_prefill_attention_ref(q, pool_kv, block_table, start):
-    """q [C, nq, hd]; pool_kv [N, bs, 2*nk, hd] head-interleaved;
-    block_table [M]; start scalar."""
+    """q [C, nq, hd]; pool_kv [N, nk, 2, bs, hd] fused; block_table [M];
+    start scalar."""
     rows_k, rows_v = cm.split_fused_kv(
         gather_paged_rows(pool_kv, block_table))
     return chunked_prefill_attention_ref(q, rows_k, rows_v, start)
 
 
 def paged_decode_attention_ref(q, pool_kv, block_tables, ctx):
-    """q [B, nq, hd]; pool_kv [N, bs, 2*nk, hd] head-interleaved;
-    block_tables [B, M]; ctx [B]."""
+    """q [B, nq, hd]; pool_kv [N, nk, 2, bs, hd] fused; block_tables
+    [B, M]; ctx [B]."""
     rows_k, rows_v = cm.split_fused_kv(
         gather_paged_rows(pool_kv, block_tables))
     return decode_attention_ref(q, rows_k, rows_v, ctx)

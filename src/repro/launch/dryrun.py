@@ -108,8 +108,6 @@ def run_one(arch: str, shape: str, multi_pod: bool, variant: str = "",
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll, coll_counts = collective_bytes(hlo)
 

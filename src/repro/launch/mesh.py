@@ -10,14 +10,10 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist on newer releases; every axis
-    here is Auto, which is also the default."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD propagates shardings
+    from the arguments; no explicit-sharding axes)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

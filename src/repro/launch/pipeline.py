@@ -13,9 +13,9 @@ tests/test_stage_partition.py).
 Placement goes through :func:`repro.launch.mesh.make_pipeline_mesh` when
 enough devices exist: stage ``s`` lives on the mesh's ``s``-th device row
 (:func:`stage_devices`).  On CPU CI the stage devices come from
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``; when fewer
-devices exist than stages, stages share devices round-robin (placement
-never affects results, only overlap).  TP *within* a stage composes with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``; fewer devices
+than stages is an error, never a silent doubling-up of stages on one
+device.  TP *within* a stage composes with
 this partition: ``PipelineEngine(tp=...)`` places each stage's param and
 cache slices over its stage row's ``model`` axis
 (:func:`repro.sharding.stage_tp_meshes` + the shared policy leaf rules),
@@ -97,18 +97,11 @@ def stage_cache(cfg: ModelConfig, cache, pp: int) -> List[Dict]:
 
 def stage_devices(pp: int, devices: Optional[Sequence] = None) -> List:
     """One device per stage: row ``s`` of the
-    :func:`repro.launch.mesh.make_pipeline_mesh` stage axis.  With fewer
-    devices than stages the mesh cannot be built and stages share devices
-    round-robin instead — results are placement-independent, only stage
-    overlap is lost."""
+    :func:`repro.launch.mesh.make_pipeline_mesh` stage axis (which raises
+    when there are fewer devices than stages)."""
     from repro.launch.mesh import make_pipeline_mesh
-    devs = list(devices) if devices is not None else list(jax.devices())
-    if not devs:
-        raise RuntimeError("no jax devices")
-    if len(devs) >= pp:
-        mesh = make_pipeline_mesh(pp, 1, devices=devs)
-        return [mesh.devices[s, 0] for s in range(pp)]
-    return [devs[s % len(devs)] for s in range(pp)]
+    mesh = make_pipeline_mesh(pp, 1, devices=devices)
+    return [mesh.devices[s, 0] for s in range(pp)]
 
 
 def place_stages(stage_trees: Sequence, devices: Sequence) -> List:

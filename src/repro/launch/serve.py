@@ -27,6 +27,9 @@ def main(argv=None):
         import os
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if args.dry_run:
         from repro.launch.dryrun import run_one
         run_one(args.arch, args.shape, args.multi_pod, args.variant)
         return

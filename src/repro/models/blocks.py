@@ -77,14 +77,16 @@ def init_attn_cache(cfg: ModelConfig, rows: int, max_len: int, dtype) -> Dict:
 def init_paged_attn_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
                           dtype) -> Dict:
     """Pooled KV for full-attention layers: ONE fused leaf ``[n_blocks,
-    block_size, 2 * nk, hd]`` with K/V head-interleaved (K head ``h`` at
-    channel ``2h``, its V at ``2h + 1``), addressed through per-request
-    block tables (``repro.cache``).  One leaf instead of split ``pk``/
-    ``pv`` halves the block-table DMA count in the Pallas kernels and
-    halves the gather/scatter count on copy-on-write forks.  The key
-    ``pkv`` (vs dense ``k``/``v``) marks the layout, so the packed path
-    and the engine's slot reset dispatch structurally."""
-    shp = (n_blocks, block_size, 2 * cfg.n_kv_heads, cfg.head_dim)
+    nk, 2, block_size, hd]`` (K at pair index 0, V at 1), addressed
+    through per-request block tables (``repro.cache``).  One leaf instead
+    of split ``pk``/``pv`` halves the block-table DMA count in the Pallas
+    kernels and halves the gather/scatter count on copy-on-write forks.
+    Head and pair sit on major axes so one head's page is whole
+    ``[block_size, hd]`` tiles (a TPU DMA may not cut the tiled minor
+    two axes) and TP splits the head axis without cutting a pair.  The
+    key ``pkv`` (vs dense ``k``/``v``) marks the layout, so the packed
+    path and the engine's slot reset dispatch structurally."""
+    shp = (n_blocks, cfg.n_kv_heads, 2, block_size, cfg.head_dim)
     return {"pkv": jnp.zeros(shp, dtype)}
 
 
@@ -171,8 +173,8 @@ _PAGED_ATTN_BACKENDS = env.REGISTRY["REPRO_PAGED_ATTN_BACKEND"].choices
 # Mesh hint for the paged Pallas kernels under tensor parallelism.  GSPMD
 # cannot partition a pallas_call, so when a TP engine runs the pallas
 # backend the kernel invocations are wrapped in shard_map over the mesh's
-# "model" axis (kv-head channel pairs stay whole per shard — the engine
-# enforces nk % tp == 0 up front).  Set by the engines immediately before
+# "model" axis (kv heads stay whole per shard — the engine enforces
+# nk % tp == 0 up front).  Set by the engines immediately before
 # each jitted step call (trace-time read, like the MoE dispatch hint).
 _PAGED_ATTN_MESH = None
 
@@ -193,49 +195,47 @@ def _paged_attn_backend() -> str:
 
 def _paged_shard_mesh(pool_kv):
     """The mesh to shard_map the pallas kernels over, or None for the
-    single-device call.  Requires whole (K, V) channel pairs per shard —
-    the placement layer rejects nk % tp != 0 before any engine is built,
-    so this only double-checks divisibility at trace time."""
+    single-device call.  Requires whole kv heads per shard — the
+    placement layer rejects nk % tp != 0 before any engine is built, so
+    this only double-checks divisibility at trace time."""
     mesh = _PAGED_ATTN_MESH
     if mesh is None:
         return None
     tp = mesh.shape.get("model", 1)
     if tp <= 1:
         return None
-    nk = pool_kv.shape[2] // 2
+    nk = pool_kv.shape[1]
     if nk % tp:
         raise ValueError(
             f"paged pallas backend under tp={tp} needs n_kv_heads "
-            f"({nk}) divisible by tp so K/V channel pairs stay whole "
-            f"per shard")
+            f"({nk}) divisible by tp so kv heads stay whole per shard")
     return mesh
 
 
 def _shard_map_heads(fn, mesh, n_table_args):
     """shard_map ``fn(q, pool_kv, <tables...>, scalar)`` over the kv-head
-    axis: q [.., nq, hd] splits heads, pool [N, bs, 2nk, hd] splits
-    channel pairs, tables/ctx replicate.  Each shard runs the unmodified
+    axis: q [.., nq, hd] splits heads, pool [N, nk, 2, bs, hd] splits its
+    head axis, tables/ctx replicate.  Each shard runs the unmodified
     single-device kernel on its local heads (block tables are physical —
     identical on every shard), so tp>1 output == concat of per-shard
     outputs over the head axis."""
-    from jax.experimental.shard_map import shard_map
     P = jax.sharding.PartitionSpec
     reps = (P(),) * n_table_args
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, "model", None),
-                  P(None, None, "model", None)) + reps,
-        out_specs=P(None, "model", None), check_rep=False)
+                  P(None, "model", None, None, None)) + reps,
+        out_specs=P(None, "model", None), check_vma=False)
 
 
 def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
     """Block-table variant of the full-attention packed path: KV written
-    through ONE (physical block, offset) scatter of the head-interleaved
-    [.., 2nk, hd] rows, read either via a fused-row gather + de-interleave
-    (XLA backend) or the fused-pool paged Pallas kernels."""
+    through ONE (physical block, offset) scatter of the fused [nk, 2, hd]
+    token rows, read either via a fused-row gather + K/V split (XLA
+    backend) or the fused-pool paged Pallas kernels."""
     C, D = pk.num_chunk, pk.num_decode
     pool_kv = cache["pkv"]
-    bs = pool_kv.shape[1]
+    bs = pool_kv.shape[3]
     M = pk.chunk_blocks.shape[0]
     use_pallas = _paged_attn_backend() == "pallas"
     if use_pallas:
@@ -249,8 +249,8 @@ def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
         bidx = cpos // bs
         phys = jnp.where(bidx < M,
                          pk.chunk_blocks[jnp.clip(bidx, 0, M - 1)], 0)
-        pool_kv = pool_kv.at[phys, cpos % bs].set(
-            cm.interleave_kv(k[:C], v[:C]))
+        pool_kv = pool_kv.at[phys, :, :, cpos % bs].set(
+            cm.fuse_kv(k[:C], v[:C]))
         if use_pallas:
             bq = 128 if C % 128 == 0 else C
             call = functools.partial(kops.paged_chunked_prefill_attention,
@@ -267,8 +267,8 @@ def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
     if D:
         bidx = (pk.decode_ctx // bs)[:, None]
         phys = jnp.take_along_axis(pk.decode_blocks, bidx, axis=1)[:, 0]
-        pool_kv = pool_kv.at[phys, pk.decode_ctx % bs].set(
-            cm.interleave_kv(k[C:], v[C:]))
+        pool_kv = pool_kv.at[phys, :, :, pk.decode_ctx % bs].set(
+            cm.fuse_kv(k[C:], v[C:]))
         if use_pallas:
             call = kops.paged_decode_attention
             if mesh is not None:

@@ -238,33 +238,30 @@ def write_kv_slot(cache, new, slot, start):
 
 
 def gather_block_rows(pool, block_tables):
-    """Paged pool -> dense rows: pool [N, bs, ch, hd] x tables [..., M]
-    -> [..., M * bs, ch, hd] in logical-position order (shared by the
-    packed paged attention path and the kernel oracles).  ``ch`` is
-    ``nk`` for split k/v pools and ``2 * nk`` for the fused pool."""
+    """Fused paged pool -> dense per-token rows: pool [N, nk, 2, bs, hd]
+    x tables [..., M] -> [..., M * bs, nk, 2, hd] in logical-position
+    order (shared by the packed paged attention path and the kernel
+    oracles); :func:`split_fused_kv` then yields the K and V rows."""
     bt = jnp.asarray(block_tables, jnp.int32)
-    rows = pool[bt]
-    shp = bt.shape[:-1] + (bt.shape[-1] * pool.shape[1],) + pool.shape[2:]
-    return rows.reshape(shp)
+    rows = jnp.moveaxis(pool[bt], -2, -4)        # [..., M, bs, nk, 2, hd]
+    return rows.reshape(bt.shape[:-1] + (bt.shape[-1] * pool.shape[3],)
+                        + rows.shape[-3:])
 
 
-def interleave_kv(k, v):
-    """Head-interleave K/V for the fused paged pool: k, v [..., nk, hd] ->
-    [..., 2 * nk, hd] with K head ``h`` at channel ``2h`` and its V at
-    ``2h + 1``.  Keeping each head's (K, V) pair adjacent is what lets one
-    block-table DMA fetch both, and keeps the pair on one shard when the
-    channel axis splits over the model axis (``nk % tp == 0``)."""
-    nk = k.shape[-2]
-    return jnp.stack([k, v], axis=-2).reshape(
-        *k.shape[:-2], 2 * nk, k.shape[-1])
+def fuse_kv(k, v):
+    """Per-token rows of the fused paged pool: k, v [..., nk, hd] ->
+    [..., nk, 2, hd] (K at pair index 0, V at 1) — what one token's write
+    ``pool[block, :, :, offset]`` stores.  The pool keeps head and pair on
+    major axes (``[N, nk, 2, bs, hd]``), so one head's page is a single
+    DMA of whole ``[bs, hd]`` tiles, and the head axis splits over the
+    model axis without cutting a (K, V) pair."""
+    return jnp.stack([k, v], axis=-2)
 
 
 def split_fused_kv(rows):
-    """Inverse of :func:`interleave_kv`: [..., 2 * nk, hd] -> (k, v) each
-    [..., nk, hd].  Pure reshape/slice — bit-exact round trip."""
-    nk = rows.shape[-2] // 2
-    pairs = rows.reshape(*rows.shape[:-2], nk, 2, rows.shape[-1])
-    return pairs[..., 0, :], pairs[..., 1, :]
+    """Inverse of :func:`fuse_kv`: [..., nk, 2, hd] -> (k, v) each
+    [..., nk, hd].  Pure slicing — bit-exact round trip."""
+    return rows[..., 0, :], rows[..., 1, :]
 
 
 def write_kv_scatter(cache, new, slots, positions):

@@ -304,13 +304,14 @@ def init_cache(cfg: ModelConfig, rows: int, max_len: int,
             raise ValueError("paged cache needs block_size")
         paged = (int(paged_blocks), int(block_size))
 
-    def one_group():
-        return [init_layer_cache(cfg, kind, rows, max_len, dtype, paged)
-                for kind in group_kinds]
-
-    groups = [one_group() for _ in range(n_groups)]
+    # every group starts from the same state: broadcast one group instead
+    # of stacking n_groups copies (which holds the copies and the stack at
+    # once — twice the KV pool in device memory)
+    group = [init_layer_cache(cfg, kind, rows, max_len, dtype, paged)
+             for kind in group_kinds]
     return {
-        "groups": jax.tree.map(lambda *xs: jnp.stack(xs), *groups),
+        "groups": jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_groups,) + x.shape), group),
         "tail": [init_layer_cache(cfg, kind, rows, max_len, dtype, paged)
                  for kind in tail_kinds],
     }
@@ -415,6 +416,40 @@ def _scan_unroll() -> int | bool:
     return env.get("REPRO_SCAN_UNROLL")
 
 
+def _scan_cached_groups(group_kinds, apply_fn, x, aux, params_groups,
+                        cache_groups, *, remat: bool = False):
+    """Scan the grouped layers with the stacked cache in the carry: each
+    step reads its group's cache slice and writes the updated slice back
+    into the stacked leaves, which XLA updates in place.  Scanning the
+    cache as xs -> ys instead allocates the outputs as a second whole
+    cache (a second KV pool's worth of device memory).  Returns
+    (x, aux, new_cache_groups)."""
+    n = jax.tree.leaves(params_groups)[0].shape[0]
+
+    def group_body(carry, xs):
+        x, aux, gcs = carry
+        gp, i = xs
+        x = _constrain_cache_act(x)
+        gc = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), gcs)
+        new_gc = []
+        for j, kind in enumerate(group_kinds):
+            x, nc, a = apply_fn(kind, gp[j], gc[j], x)
+            new_gc.append(nc)
+            aux = aux + a
+        gcs = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+            gcs, new_gc)
+        return (x, aux, gcs), None
+
+    body = jax.checkpoint(group_body, prevent_cse=False) if remat else group_body
+    (x, aux, new_groups), _ = jax.lax.scan(
+        body, (x, aux, cache_groups),
+        (params_groups, jnp.arange(n, dtype=jnp.int32)),
+        unroll=_scan_unroll())
+    return x, aux, new_groups
+
+
 def _run_layers(cfg, params, cache, x, apply_fn, remat: bool):
     """Scan the grouped layers then the tail.  ``apply_fn(kind, p, c, x)``
     -> (x, new_c, aux)."""
@@ -423,21 +458,9 @@ def _run_layers(cfg, params, cache, x, apply_fn, remat: bool):
     unroll = _scan_unroll()
 
     if has_cache:
-        def group_body(carry, xs):
-            x, aux = carry
-            x = _constrain_cache_act(x)
-            gp, gc = xs
-            new_gc = []
-            for j, kind in enumerate(group_kinds):
-                x, nc, a = apply_fn(kind, gp[j], gc[j], x)
-                new_gc.append(nc)
-                aux = aux + a
-            return (x, aux), new_gc
-
-        body = jax.checkpoint(group_body, prevent_cse=False) if remat else group_body
-        (x, aux), new_groups = jax.lax.scan(
-            body, (x, jnp.float32(0.0)), (params["groups"], cache["groups"]),
-            unroll=unroll)
+        x, aux, new_groups = _scan_cached_groups(
+            group_kinds, apply_fn, x, jnp.float32(0.0), params["groups"],
+            cache["groups"], remat=remat)
         new_tail = []
         for j, kind in enumerate(tail_kinds):
             x, nc, a = apply_fn(kind, params["tail"][j], cache["tail"][j], x)
@@ -534,21 +557,8 @@ def forward_packed_stage(cfg: ModelConfig, params, pk: PackedBatch, cache,
     aux = jnp.float32(0.0)
     new_cache = {}
     if "groups" in cache:
-        def group_body(carry, xs):
-            x, aux = carry
-            x = _constrain_cache_act(x)
-            gp, gc = xs
-            new_gc = []
-            for j, kind in enumerate(group_kinds):
-                x, nc, a = apply_fn(kind, gp[j], gc[j], x)
-                new_gc.append(nc)
-                aux = aux + a
-            return (x, aux), new_gc
-
-        (x, aux), new_groups = jax.lax.scan(
-            group_body, (x, aux), (params["groups"], cache["groups"]),
-            unroll=_scan_unroll())
-        new_cache["groups"] = new_groups
+        x, aux, new_cache["groups"] = _scan_cached_groups(
+            group_kinds, apply_fn, x, aux, params["groups"], cache["groups"])
     if "tail" in cache:
         new_tail = []
         for j, kind in enumerate(tail_kinds):

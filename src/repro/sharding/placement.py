@@ -103,9 +103,9 @@ def check_tp_supported(tp: int, paged: bool,
     """TP support check for the paged attention backends.  GSPMD cannot
     partition a ``pallas_call``, so the block-table kernels run under
     shard_map over the kv-head axis instead (``repro.models.blocks``) —
-    which needs whole head-interleaved (K, V) channel pairs per shard,
-    i.e. ``n_kv_heads % tp == 0``.  Reject the indivisible case up front
-    instead of failing opaquely at trace time; the XLA gather backend
+    which needs whole kv heads per shard, i.e. ``n_kv_heads % tp == 0``.
+    Reject the indivisible case up front instead of failing opaquely at
+    trace time; the XLA gather backend
     partitions under any divisibility (the policy falls back to block or
     head_dim sharding)."""
     if tp <= 1 or not paged:
@@ -119,5 +119,5 @@ def check_tp_supported(tp: int, paged: bool,
             f"tp={tp} with the paged pallas attention backend needs "
             f"n_kv_heads divisible by tp (got n_kv_heads={nk}): the "
             f"kernels shard_map over the kv-head axis and each shard "
-            f"must hold whole K/V channel pairs; use "
+            f"must hold whole kv heads; use "
             f"REPRO_PAGED_ATTN_BACKEND=xla for this config")

@@ -242,12 +242,12 @@ def cache_pspecs(cfg: ModelConfig, shapes, *,
                  model_axis: Optional[int] = None):
     """Cache leaves: row (slot) dim shards over the batch axes; KV head /
     state-head dims shard over model when divisible.  The fused paged
-    block-pool leaf (``pkv``, ``[n_blocks, block_size, 2 * nk, hd]``
-    head-interleaved) has no row dim — it shards the channel axis over
-    model when ``nk`` divides (keeping each head's adjacent (K, V) pair
-    on one shard), falling back to the block dim (context-parallel
-    analogue) or head_dim per :func:`kv_shard_mode`, so the pool never
-    silently replicates under TP."""
+    block-pool leaf (``pkv``, ``[n_blocks, nk, 2, block_size, hd]``) has
+    no row dim — it shards the kv-head axis over model when ``nk``
+    divides (each head's (K, V) pair stays on one shard), falling back
+    to the block dim (context-parallel analogue) or head_dim per
+    :func:`kv_shard_mode`, so the pool never silently replicates under
+    TP."""
     if mesh is not None:
         if model_axis is not None:
             raise ValueError("pass either mesh= or model_axis=, not both")
@@ -284,16 +284,14 @@ def cache_pspecs(cfg: ModelConfig, shapes, *,
             if kv_mode in ("seq", "hd") and div(shp[-1]):
                 return spec(rspec, None, None, MDL)
             return spec(rspec, None, None, None)
-        if name == "pkv":                   # fused pool [N, bs, 2nk, hd]
-            # channel pairs (K head h at 2h, V at 2h+1) must stay whole
-            # per shard: split only when nk itself divides the model axis
-            if shp[-2] % 2 == 0 and div(shp[-2] // 2):
-                return spec(None, None, MDL, None)
-            if kv_mode == "seq" and div(shp[-4]):
-                return spec(MDL, None, None, None)       # block parallel
+        if name == "pkv":                   # fused pool [N, nk, 2, bs, hd]
+            if div(shp[-4]):
+                return spec(None, MDL, None, None, None)
+            if kv_mode == "seq" and div(shp[-5]):
+                return spec(MDL, None, None, None, None)  # block parallel
             if kv_mode in ("seq", "hd") and div(shp[-1]):
-                return spec(None, None, None, MDL)
-            return spec(None, None, None, None)
+                return spec(None, None, None, None, MDL)
+            return spec(None, None, None, None, None)
         if name == "pos":                   # [rows, W]
             return spec(rspec, None)
         if name == "state":                 # [rows, nh, P, N]

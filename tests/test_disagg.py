@@ -123,8 +123,8 @@ def test_disagg_tp2_bit_identical_to_tp2_monolithic(paged):
 @pytest.mark.parametrize("paged", [False, True])
 def test_disagg_cross_pp_bit_identical(paged):
     """pp=2 prefill replica handing off to a pp=1 decode replica: stage
-    slices reassemble into the canonical payload (stages share devices
-    round-robin when fewer exist, results are placement-independent)."""
+    slices reassemble into the canonical payload (each stage on its own
+    host device; results are placement-independent)."""
     cfg, _ = _cfg_params()
     cfg4 = dataclasses.replace(cfg, n_layers=4)
     params4 = build_model(cfg4).init_params(jax.random.PRNGKey(0))

@@ -79,25 +79,36 @@ def test_interleave_split_roundtrip(n_blocks, bs, nk, hd, seed):
     rng = np.random.default_rng(seed)
     k = rng.standard_normal((n_blocks, bs, nk, hd)).astype(np.float32)
     v = rng.standard_normal((n_blocks, bs, nk, hd)).astype(np.float32)
-    fused = cm.interleave_kv(jnp.asarray(k), jnp.asarray(v))
-    assert fused.shape == (n_blocks, bs, 2 * nk, hd)
+    fused = cm.fuse_kv(jnp.asarray(k), jnp.asarray(v))
+    assert fused.shape == (n_blocks, bs, nk, 2, hd)
     k2, v2 = cm.split_fused_kv(fused)
     np.testing.assert_array_equal(np.asarray(k2), k)
     np.testing.assert_array_equal(np.asarray(v2), v)
+    # pool layout [N, nk, 2, bs, hd]: gathering every block in order
+    # restores the per-token rows exactly
+    pool = ref.fuse_kv_pools(jnp.asarray(k), jnp.asarray(v))
+    assert pool.shape == (n_blocks, nk, 2, bs, hd)
+    rows = cm.gather_block_rows(pool, np.arange(n_blocks))
+    np.testing.assert_array_equal(
+        np.asarray(rows), np.asarray(fused).reshape(n_blocks * bs, nk, 2, hd))
 
 
 def test_fused_channel_order_is_kv_pairs():
-    """K head h lives at channel 2h, V head h at 2h+1 — the contract the
-    Pallas kernels' per-head channel-pair DMA relies on."""
-    nk, hd = 3, 4
-    k = jnp.arange(nk * hd, dtype=jnp.float32).reshape(1, 1, nk, hd)
-    v = -jnp.arange(nk * hd, dtype=jnp.float32).reshape(1, 1, nk, hd)
-    fused = cm.interleave_kv(k, v)
+    """K head h of token o in block n lives at pool[n, h, 0, o], its V at
+    pool[n, h, 1, o] — the contract the Pallas kernels' one-DMA-per-page
+    fetch of ``pool[block, head]`` relies on: head and pair are major
+    axes, so the copy never cuts the tiled [bs, hd] minor axes."""
+    nk, hd, bs = 3, 4, 2
+    k = jnp.arange(bs * nk * hd, dtype=jnp.float32).reshape(1, bs, nk, hd)
+    v = -k
+    fused = cm.fuse_kv(k, v)
+    pool = ref.fuse_kv_pools(k, v)
     for h in range(nk):
-        np.testing.assert_array_equal(fused[0, 0, 2 * h], k[0, 0, h])
-        np.testing.assert_array_equal(fused[0, 0, 2 * h + 1], v[0, 0, h])
-    np.testing.assert_array_equal(
-        np.asarray(ref.fuse_kv_pools(k, v)), np.asarray(fused))
+        np.testing.assert_array_equal(fused[0, :, h, 0], k[0, :, h])
+        np.testing.assert_array_equal(fused[0, :, h, 1], v[0, :, h])
+        for o in range(bs):
+            np.testing.assert_array_equal(pool[0, h, 0, o], k[0, o, h])
+            np.testing.assert_array_equal(pool[0, h, 1, o], v[0, o, h])
 
 
 # ------------------------------------------- engine-level fused identity

@@ -73,7 +73,7 @@ def test_shape_support_matrix():
 
 
 def test_paged_pool_leaves_shard_on_production_mesh():
-    """The fused pkv pool leaf [n_blocks, bs, 2*nk, hd] must carry
+    """The fused pkv pool leaf [n_blocks, nk, 2, bs, hd] must carry
     model-axis specs — the paged cache must not silently replicate
     under TP."""
     import functools
@@ -86,12 +86,12 @@ def test_paged_pool_leaves_shard_on_production_mesh():
     pool = specs["groups"][0]["attn"]
     # tinyllama GQA: nk=4 doesn't divide 16, nor do the 33 blocks; the
     # default "seq" mode falls back to head_dim (64 % 16 == 0)
-    assert pool["pkv"] == P(None, None, None, None, "model")
-    # at tp=2 the channel dim shards by whole K/V pairs (4 % 2 == 0)
-    m2 = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+    assert pool["pkv"] == P(None, None, None, None, None, "model")
+    # at tp=2 the kv-head dim shards, whole K/V pairs per head (4 % 2 == 0)
+    m2 = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
     pool2 = sh.cache_pspecs(cfg, cshapes, rows_axes=None,
                             mesh=m2)["groups"][0]["attn"]
-    assert pool2["pkv"] == P(None, None, None, "model", None)
+    assert pool2["pkv"] == P(None, None, "model", None, None, None)
 
 
 def test_policy_is_shared_with_serving_layer():

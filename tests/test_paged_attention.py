@@ -76,7 +76,7 @@ def test_paged_decode_attention_buffering_variants(kv_pages, n_buffers):
     N = B * M + 1
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, nq, hd))
-    pool = jax.random.normal(ks[1], (N, bs, 2 * nk, hd))
+    pool = jax.random.normal(ks[1], (N, nk, 2, bs, hd))
     perm = np.random.default_rng(4).permutation(np.arange(1, N))
     bt = perm[:B * M].reshape(B, M).astype(np.int32)
     ctx = jnp.array([3, 37, 79], jnp.int32)
@@ -96,7 +96,7 @@ def test_paged_chunked_prefill_attention_buffering_variants(kv_pages,
     N = M + 3
     ks = jax.random.split(jax.random.PRNGKey(11), 2)
     q = jax.random.normal(ks[0], (C, nq, hd))
-    pool = jax.random.normal(ks[1], (N, bs, 2 * nk, hd))
+    pool = jax.random.normal(ks[1], (N, nk, 2, bs, hd))
     bt = np.random.default_rng(5).permutation(np.arange(1, N))[:M] \
         .astype(np.int32)
     want = ref.paged_chunked_prefill_attention_ref(q, pool, bt, start)
@@ -114,7 +114,7 @@ def test_paged_kernels_ignore_scratch_padded_tail():
     N = B * M + 1
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     q = jax.random.normal(ks[0], (B, nq, hd))
-    pool = jax.random.normal(ks[1], (N, bs, 2 * nk, hd))
+    pool = jax.random.normal(ks[1], (N, nk, 2, bs, hd))
     bt = np.arange(1, 1 + B * M).reshape(B, M).astype(np.int32)
     ctx = jnp.array([20, 40])
     bt_padded = bt.copy()

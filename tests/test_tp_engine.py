@@ -133,15 +133,15 @@ def test_engines_and_launch_share_one_policy():
 
 
 def test_paged_pool_leaves_have_tp_specs():
-    """Satellite: the fused pkv pool leaf [n_blocks, bs, 2*nk, hd] must
-    shard under TP (channel-pair dim here: nk=2 divides tp=2), not
+    """Satellite: the fused pkv pool leaf [n_blocks, nk, 2, bs, hd] must
+    shard under TP (kv-head dim here: nk=2 divides tp=2), not
     replicate."""
     cfg, _ = _cfg_params()
     model = build_model(cfg)
     shapes = jax.eval_shape(
         lambda: model.init_cache(3, 64, jax.numpy.float32,
                                  paged_blocks=17, block_size=8))
-    mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+    mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
     specs = shd.cache_pspecs(cfg, shapes, rows_axes=None, mesh=mesh)
 
     found = []
@@ -155,9 +155,9 @@ def test_paged_pool_leaves_have_tp_specs():
     assert found, "no pool leaves in the paged cache spec tree"
     for spec in found:
         assert "model" in tuple(spec), f"pool leaf replicated: {spec}"
-        # the channel axis is the sharded one: adjacent (K, V) pairs must
-        # land on one shard, which needs nk (not 2nk) to divide tp
-        assert tuple(spec)[-2] == "model"
+        # the kv-head axis is the sharded one: each head's (K, V) pair
+        # stays on one shard
+        assert tuple(spec)[-4] == "model"
 
 
 def test_mesh_derived_axis_sizes():
@@ -168,7 +168,7 @@ def test_mesh_derived_axis_sizes():
     cfg = get_config("tinyllama-1.1b")
     shapes = jax.eval_shape(
         lambda: build_model(cfg).init_params(jax.random.PRNGKey(0)))
-    m3 = jax.sharding.AbstractMesh((("data", 1), ("model", 3)))
+    m3 = jax.sharding.AbstractMesh((1, 3), ("data", "model"))
     specs = shd.param_pspecs(cfg, shapes, mesh=m3)
     # 32000 % 3 != 0 -> embed replicates on the 3-mesh, shards on 16
     assert specs["embed"] == jax.sharding.PartitionSpec(None, None)
@@ -258,7 +258,7 @@ def test_tp2_paged_pallas_backend_accepted(monkeypatch):
 
 
 def test_tp_paged_pallas_needs_divisible_kv_heads(monkeypatch):
-    """Residual restriction: shard_map keeps whole K/V channel pairs per
+    """Residual restriction: shard_map keeps whole kv heads per
     shard, so nk % tp != 0 (here 2 % 3) is still rejected up front."""
     monkeypatch.setenv("REPRO_PAGED_ATTN_BACKEND", "pallas")
     cfg, params = _cfg_params()
