@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.cache import BlockManager
 from repro.configs.base import ModelConfig
 from repro.core.sampling import SamplingParams, sample
@@ -389,6 +390,7 @@ class Engine:
         return self.block_manager is not None
 
     # ----------------------------------------------------------- requests
+    @obs.spanned("engine.add_request")
     def add_request(self, req_id: int, memory=None) -> int:
         """Assign a cache slot; seed cross-attention KV if the architecture
         consumes frontend embeddings (VLM image tiles / audio frames)."""
@@ -414,6 +416,7 @@ class Engine:
             memory = self.model.encode(self.params, memory[None])[0]
         self.cache = self._seed_cross(self.params, self.cache, memory, slot)
 
+    @obs.spanned("engine.release")
     def release(self, req_id: int):
         slot = self._slot_of.pop(req_id)
         self._free.append(slot)
@@ -551,6 +554,7 @@ class Engine:
         rows = self._arena_fetch(arena, [s for s, _ in pairs], len(dst))
         return self._scatter_pool(cache, rows, dst)
 
+    @obs.spanned("engine.swap_out")
     def swap_out_blocks(self, pairs: Sequence[tuple]):
         """Device->host move for :meth:`BlockManager.swap_out` pairs: the
         named device blocks' KV contents land in the host arena rows.
@@ -562,6 +566,7 @@ class Engine:
             self._host_pool = self._host_pool_for(self.cache)
         self._swap_out_one(self.cache, self._host_pool, pairs)
 
+    @obs.spanned("engine.swap_in")
     def swap_in_blocks(self, pairs: Sequence[tuple]):
         """Host->device move for :meth:`BlockManager.swap_in` pairs,
         before the resumed request's next chunk: restores the exact KV
@@ -577,18 +582,20 @@ class Engine:
     def _step_impl(self, params, pk: PackedBatch, cache, key):
         chunk_logits, decode_logits, cache, _ = \
             self.model.forward_packed(params, pk, cache)
-        kc, kd = jax.random.split(key)
-        chunk_tok = (sample(chunk_logits[0], kc, self.sampling)
-                     if chunk_logits is not None else None)
-        # sample only the REAL decode rows: SP pads the lanes to a
-        # multiple of tp, and the PRNG's noise depends on the array
-        # shape, so sampling the padded [lane_D, V] block would change
-        # every stochastic decode stream vs the unpadded engine (a
-        # static slice; no-op when the lanes are unpadded)
-        dec_tok = (sample(decode_logits[:self.D], kd, self.sampling)
-                   if decode_logits is not None else None)
+        with jax.named_scope("sample"):
+            kc, kd = jax.random.split(key)
+            chunk_tok = (sample(chunk_logits[0], kc, self.sampling)
+                         if chunk_logits is not None else None)
+            # sample only the REAL decode rows: SP pads the lanes to a
+            # multiple of tp, and the PRNG's noise depends on the array
+            # shape, so sampling the padded [lane_D, V] block would change
+            # every stochastic decode stream vs the unpadded engine (a
+            # static slice; no-op when the lanes are unpadded)
+            dec_tok = (sample(decode_logits[:self.D], kd, self.sampling)
+                       if decode_logits is not None else None)
         return chunk_tok, dec_tok, chunk_logits, cache
 
+    @obs.spanned("engine.execute")
     def execute(self, plan: IterationPlan) -> Dict[int, int]:
         """Run one iteration; returns {req_id: newly sampled token} for the
         requests that produced a token this iteration.
@@ -622,6 +629,7 @@ class Engine:
         self._execute_packed(None, [])
         self._key, self.iterations = key, n
 
+    @obs.spanned("engine.pack")
     def _pack(self, chunk: Optional[ChunkWork],
               decodes: Sequence[DecodeWork],
               pad_chunk: bool = False) -> PackedBatch:
@@ -687,6 +695,7 @@ class Engine:
             decode_ctx=jnp.asarray(dc), chunk_blocks=jnp.asarray(cb),
             decode_blocks=jnp.asarray(db))
 
+    @obs.spanned("engine.cow")
     def _apply_cow(self, pairs: Sequence[tuple]):
         """Run the copy-on-write block copies on device, before the packed
         step whose writes they protect."""
@@ -694,6 +703,7 @@ class Engine:
         self.cache = self._cow_blocks(self.cache, src, dst)
 
     @staticmethod
+    @obs.spanned("engine.collect")
     def _collect(chunk: Optional[ChunkWork], decodes: Sequence[DecodeWork],
                  chunk_tok, dec_tok) -> Dict[int, int]:
         out: Dict[int, int] = {}
@@ -720,7 +730,8 @@ class Engine:
         # engine never traces under another engine's stale sharding)
         from repro.models import stack as _stack
         _stack.set_packed_sp_sharding(self._sp_sharding)
-        chunk_tok, dec_tok, self.chunk_logits, self.cache = self._step(
-            self.params, pk, self.cache, sub)
+        with obs.span("engine.launch"):
+            chunk_tok, dec_tok, self.chunk_logits, self.cache = self._step(
+                self.params, pk, self.cache, sub)
         self.iterations += 1
         return self._collect(chunk, decodes, chunk_tok, dec_tok)

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.cache import BlockManager
 from repro.configs.base import ModelConfig
 from repro.core.engine import (ChunkWork, DecodeWork, Engine, IterationPlan,
@@ -170,6 +171,7 @@ class PipelineEngine(Engine):
         raise NotImplementedError("PipelineEngine does not support "
                                   "frontend-memory architectures yet")
 
+    @obs.spanned("engine.cow")
     def _apply_cow(self, pairs: Sequence[tuple]):
         # one engine-wide block id space; every stage's pool forks the
         # same (src, dst) pairs on its own cache slice
@@ -177,6 +179,7 @@ class PipelineEngine(Engine):
         self.stage_caches = [self._cow_blocks(c, src, dst)
                              for c in self.stage_caches]
 
+    @obs.spanned("engine.swap_out")
     def swap_out_blocks(self, pairs: Sequence[tuple]):
         # one engine-wide block id space, one host arena per stage: the
         # same (device_block, host_slot) moves replay on every stage's
@@ -189,6 +192,7 @@ class PipelineEngine(Engine):
         for c, a in zip(self.stage_caches, self._host_pool):
             self._swap_out_one(c, a, pairs)
 
+    @obs.spanned("engine.swap_in")
     def swap_in_blocks(self, pairs: Sequence[tuple]):
         if not pairs:
             return

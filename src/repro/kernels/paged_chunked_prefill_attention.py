@@ -138,6 +138,7 @@ def paged_chunked_prefill_attention(q, pool_kv, block_table, start, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nq, C, hd), q.dtype),
         interpret=interpret,
+        name="paged_chunked_prefill_attention",
     )(jnp.asarray(start, jnp.int32).reshape(1),
       jnp.asarray(block_table, jnp.int32), qh, pool_kv)
     return jnp.moveaxis(out, 0, 1)

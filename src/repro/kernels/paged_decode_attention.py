@@ -142,6 +142,7 @@ def paged_decode_attention(q, pool_kv, block_tables, ctx, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nk, g, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(jnp.asarray(ctx, jnp.int32), jnp.asarray(block_tables, jnp.int32),
       qh, pool_kv)
     return out.reshape(B, nq, hd)

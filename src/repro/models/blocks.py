@@ -246,39 +246,48 @@ def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
         cpos = pos[:C]
         # padding lanes past max_len must NOT clamp into the table's last
         # (live) block — route them to the reserved scratch block instead
-        bidx = cpos // bs
-        phys = jnp.where(bidx < M,
-                         pk.chunk_blocks[jnp.clip(bidx, 0, M - 1)], 0)
-        pool_kv = pool_kv.at[phys, :, :, cpos % bs].set(
-            cm.fuse_kv(k[:C], v[:C]))
+        with jax.named_scope("kv_write"):
+            bidx = cpos // bs
+            phys = jnp.where(bidx < M,
+                             pk.chunk_blocks[jnp.clip(bidx, 0, M - 1)], 0)
+            pool_kv = pool_kv.at[phys, :, :, cpos % bs].set(
+                cm.fuse_kv(k[:C], v[:C]))
         if use_pallas:
             bq = 128 if C % 128 == 0 else C
             call = functools.partial(kops.paged_chunked_prefill_attention,
                                      bq=bq)
             if mesh is not None:
                 call = _shard_map_heads(call, mesh, n_table_args=2)
-            out_c = call(q[:C], pool_kv, pk.chunk_blocks, pk.chunk_start)
+            with jax.named_scope("attn"):
+                out_c = call(q[:C], pool_kv, pk.chunk_blocks,
+                             pk.chunk_start)
         else:
-            rows = cm.gather_block_rows(pool_kv, pk.chunk_blocks)
-            row_k, row_v = cm.split_fused_kv(rows)
-            out_c = cm.blocked_gqa_attention(q[None, :C], row_k[None],
-                                             row_v[None], cpos[None])[0]
+            with jax.named_scope("kv_read"):
+                rows = cm.gather_block_rows(pool_kv, pk.chunk_blocks)
+                row_k, row_v = cm.split_fused_kv(rows)
+            with jax.named_scope("attn"):
+                out_c = cm.blocked_gqa_attention(
+                    q[None, :C], row_k[None], row_v[None], cpos[None])[0]
         outs.append(out_c)
     if D:
-        bidx = (pk.decode_ctx // bs)[:, None]
-        phys = jnp.take_along_axis(pk.decode_blocks, bidx, axis=1)[:, 0]
-        pool_kv = pool_kv.at[phys, :, :, pk.decode_ctx % bs].set(
-            cm.fuse_kv(k[C:], v[C:]))
+        with jax.named_scope("kv_write"):
+            bidx = (pk.decode_ctx // bs)[:, None]
+            phys = jnp.take_along_axis(pk.decode_blocks, bidx, axis=1)[:, 0]
+            pool_kv = pool_kv.at[phys, :, :, pk.decode_ctx % bs].set(
+                cm.fuse_kv(k[C:], v[C:]))
         if use_pallas:
             call = kops.paged_decode_attention
             if mesh is not None:
                 call = _shard_map_heads(call, mesh, n_table_args=2)
-            out_d = call(q[C:], pool_kv, pk.decode_blocks, pk.decode_ctx)
+            with jax.named_scope("attn"):
+                out_d = call(q[C:], pool_kv, pk.decode_blocks, pk.decode_ctx)
         else:
-            rows = cm.gather_block_rows(pool_kv, pk.decode_blocks)
-            gk, gv = cm.split_fused_kv(rows)
-            out_d = cm.blocked_gqa_attention(
-                q[C:, None], gk, gv, pk.decode_ctx[:, None])[:, 0]
+            with jax.named_scope("kv_read"):
+                rows = cm.gather_block_rows(pool_kv, pk.decode_blocks)
+                gk, gv = cm.split_fused_kv(rows)
+            with jax.named_scope("attn"):
+                out_d = cm.blocked_gqa_attention(
+                    q[C:, None], gk, gv, pk.decode_ctx[:, None])[:, 0]
         outs.append(out_d)
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
     return out, {"pkv": pool_kv}
@@ -288,16 +297,18 @@ def attn_packed(cfg, p, x, cache, pk: PackedBatch,
                 window: Optional[int] = None):
     """x [T, d] packed hybrid batch."""
     C, D = pk.num_chunk, pk.num_decode
-    q, k, v = _qkv(cfg, p, x)
-    pos = pk.positions()
-    sin, cos = cm.rope_sin_cos(pos, cfg.head_dim, cfg.rope_theta)
-    q = cm.apply_rope(q, sin, cos)
-    k = cm.apply_rope(k, sin, cos)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(cfg, p, x)
+        pos = pk.positions()
+        sin, cos = cm.rope_sin_cos(pos, cfg.head_dim, cfg.rope_theta)
+        q = cm.apply_rope(q, sin, cos)
+        k = cm.apply_rope(k, sin, cos)
 
     if "pkv" in cache:
         assert window is None, "window caches are slot-indexed, not paged"
         out, new_cache = _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk)
-        return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
+        with jax.named_scope("o_proj"):
+            return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
 
     outs = []
     if window is None:
@@ -369,7 +380,8 @@ def attn_packed(cfg, p, x, cache, pk: PackedBatch,
         new_cache = {"k": rk, "v": rv, "pos": rpos}
 
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-    return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
+    with jax.named_scope("o_proj"):
+        return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
 
 
 def cross_packed(cfg, p, x, cache, pk: PackedBatch):

@@ -247,7 +247,8 @@ def apply_layer_packed(cfg, kind, p, x, cache, pk: PackedBatch):
     # row-parallel matmul's all-reduce splits into reduce-scatter (here) +
     # all-gather (in front of the next sharded matmul, inserted by GSPMD)
     x = _sp_scatter(x + mo)
-    fo, aux = _apply_ffn(cfg, kind, p, x)
+    with jax.named_scope("ffn"):
+        fo, aux = _apply_ffn(cfg, kind, p, x)
     if fo is not None:
         x = _sp_scatter(x + fo)
     return x, new_cache, aux
@@ -430,16 +431,19 @@ def _scan_cached_groups(group_kinds, apply_fn, x, aux, params_groups,
         x, aux, gcs = carry
         gp, i = xs
         x = _constrain_cache_act(x)
-        gc = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), gcs)
+        with jax.named_scope("kv_carry"):
+            gc = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                gcs)
         new_gc = []
         for j, kind in enumerate(group_kinds):
             x, nc, a = apply_fn(kind, gp[j], gc[j], x)
             new_gc.append(nc)
             aux = aux + a
-        gcs = jax.tree.map(
-            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
-            gcs, new_gc)
+        with jax.named_scope("kv_carry"):
+            gcs = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+                gcs, new_gc)
         return (x, aux, gcs), None
 
     body = jax.checkpoint(group_body, prevent_cse=False) if remat else group_body
@@ -548,7 +552,8 @@ def forward_packed_stage(cfg: ModelConfig, params, pk: PackedBatch, cache,
     """
     group_kinds, _, tail_kinds = group_split(cfg)
     if first:
-        x = jnp.take(params["embed"], pk.token_ids(), axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], pk.token_ids(), axis=0)
     x = _sp_scatter(x)      # SP entry: shard the residual carry up front
 
     def apply_fn(kind, p, c, x):
@@ -572,16 +577,18 @@ def forward_packed_stage(cfg: ModelConfig, params, pk: PackedBatch, cache,
     # SP exit: the final all-gather — the dynamic chunk-row slice and the
     # decode-lane split below index arbitrary token rows
     x = _sp_gather(x)
-    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     C, D = pk.num_chunk, pk.num_decode
-    if C:
-        # last *valid* chunk row (the chunk may be padded past chunk_len)
-        last_row = jax.lax.dynamic_slice_in_dim(
-            x, jnp.maximum(pk.chunk_len - 1, 0), 1, axis=0)
-        chunk_logits = _unembed(cfg, params, last_row)
-    else:
-        chunk_logits = None
-    decode_logits = _unembed(cfg, params, x[C:]) if D else None
+    with jax.named_scope("unembed"):
+        x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if C:
+            # last *valid* chunk row (the chunk may be padded past
+            # chunk_len)
+            last_row = jax.lax.dynamic_slice_in_dim(
+                x, jnp.maximum(pk.chunk_len - 1, 0), 1, axis=0)
+            chunk_logits = _unembed(cfg, params, last_row)
+        else:
+            chunk_logits = None
+        decode_logits = _unembed(cfg, params, x[C:]) if D else None
     return (chunk_logits, decode_logits), new_cache, aux
 
 

@@ -77,6 +77,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from repro import obs
 from repro.core.engine import DecodeWork, IterationPlan
 from repro.scheduler.policies import POLICIES, Scheduler
 from repro.scheduler.request import Request, State
@@ -161,6 +162,7 @@ class SarathiServeScheduler(Scheduler):
         self.n_swap_ins = 0             # swapped victims resumed
 
     # ------------------------------------------------------------- intake
+    @obs.spanned("sched.admit")
     def _admit(self, admit_hook=None, now: Optional[float] = None,
                swap_in_hook=None):
         if self.admit_backoff:
@@ -306,6 +308,7 @@ class SarathiServeScheduler(Scheduler):
                                       victim.context_len, self.chunk_size)
         return swap_t < rec_t
 
+    @obs.spanned("sched.preempt")
     def _preempt(self, victim: Request, preempt_hook=None,
                  swap_out_hook=None):
         """Evict ``victim`` and re-queue it at the head of the waiting
@@ -349,6 +352,7 @@ class SarathiServeScheduler(Scheduler):
         return None
 
     # ------------------------------------------------------------- policy
+    @obs.spanned("sched.next_plan")
     def next_plan(self, admit_hook=None, now: Optional[float] = None,
                   preempt_hook=None, swap_out_hook=None,
                   swap_in_hook=None) -> Optional[IterationPlan]:
@@ -418,7 +422,7 @@ class SarathiServeScheduler(Scheduler):
                 if n <= 0:
                     break
                 bm.ensure(r.req_id, r.prefilled + n)
-            plan.chunks.append(self._take_chunk(r, n))
+            plan.chunks.append(self._take_chunk(r, n, now))
             budget -= n
         if not plan.chunks and not plan.decodes:
             return None

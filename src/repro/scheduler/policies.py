@@ -19,6 +19,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
+from repro import obs
 from repro.core.engine import ChunkWork, DecodeWork, IterationPlan
 from repro.scheduler.request import Request, State
 
@@ -58,6 +59,7 @@ class Scheduler:
                 admit_hook(req)
 
     # ------------------------------------------------------------ results
+    @obs.spanned("sched.on_tokens")
     def on_tokens(self, tokens: Dict[int, int], release_hook=None):
         """Feed sampled tokens back; retire finished requests."""
         by_id = {r.req_id: r for r in self.running}
@@ -83,11 +85,16 @@ class Scheduler:
     def has_work(self) -> bool:
         return bool(self.waiting or self.running)
 
-    def _take_chunk(self, req: Request, n: int) -> ChunkWork:
+    def _take_chunk(self, req: Request, n: int,
+                    now: Optional[float] = None) -> ChunkWork:
         """Cut the next ``n``-token prefill chunk off ``req`` and advance
         its lifecycle (prefilled counter, PREFILLING -> DECODING on the
         last chunk).  ``prefill_tokens`` is the prompt, plus — after a
-        preemption — the generated tokens being recomputed."""
+        preemption — the generated tokens being recomputed.  The first
+        chunk ever taken stamps ``req.first_scheduled`` with ``now``, the
+        loop's clock (kept across preemptions)."""
+        if req.first_scheduled is None:
+            req.first_scheduled = now
         toks = list(req.prefill_tokens[req.prefilled: req.prefilled + n])
         chunk = ChunkWork(req.req_id, toks, req.prefilled,
                           is_last=(n == req.prefill_remaining))

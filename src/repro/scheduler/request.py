@@ -50,6 +50,7 @@ class Request:
     swapped_tokens: int = 0                  # context moved to host overall
 
     # bookkeeping for metrics
+    first_scheduled: Optional[float] = None  # loop clock at the first chunk
     first_token_iter: Optional[int] = None
     finish_iter: Optional[int] = None
 

@@ -226,6 +226,31 @@ def test_preempted_request_readmits_past_watermark():
     assert len(req.output) == 6
 
 
+def test_first_scheduled_stamped_at_first_chunk_and_kept_across_preemption():
+    """``first_scheduled`` is the loop clock at the request's first chunk:
+    not stamped while the request waits for its arrival, not moved by
+    later chunks, and kept when a preemption re-queues the request."""
+    from repro.cache import BlockManager
+    bm = BlockManager(64, 4)
+    sched = SarathiServeScheduler(n_slots=2, max_decodes=1, chunk_size=8,
+                                  token_budget=9, block_manager=bm)
+    req = Request(prompt=[1] * 20, max_new_tokens=4, arrival_time=1.0)
+    sched.submit(req)
+    assert sched.next_plan(now=0.5) is None           # not arrived yet
+    assert req.first_scheduled is None
+    plan = sched.next_plan(now=2.0)
+    assert [c.start for c in plan.chunks] == [0]
+    assert req.first_scheduled == 2.0
+    plan = sched.next_plan(now=3.0)
+    assert [c.start for c in plan.chunks] == [8]
+    assert req.first_scheduled == 2.0
+    sched._preempt(req)
+    assert req.n_preemptions == 1 and req.prefilled == 0
+    plan = sched.next_plan(now=5.0)                   # re-prefill from 0
+    assert [c.start for c in plan.chunks] == [0]
+    assert req.first_scheduled == 2.0
+
+
 def test_concurrent_oversized_prefills_do_not_wedge_tiny_pool():
     """Regression for the admit-then-starve race: admission used to check
     the whole prompt against the INSTANTANEOUS free list, so two prompts

@@ -102,3 +102,11 @@ def test_shard_mapped_decode_kernel_compiles_on_four_chips(topo):
             arg((DECODE_LANES,), jnp.int32, P()))
     kernel = functools.partial(pda.paged_decode_attention, interpret=False)
     _compiles(bk._shard_map_heads(kernel, mesh, n_table_args=2), *args)
+
+
+def test_named_scopes_change_no_tpu_program(topo, monkeypatch):
+    """The packed step's named scopes change only metadata and names in
+    what the TPU compiler makes of both step shapes (a tiny engine)."""
+    from test_obs import assert_scopes_change_only_metadata
+    assert_scopes_change_only_metadata(
+        monkeypatch, SingleDeviceSharding(topo.devices[0]))
