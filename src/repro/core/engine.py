@@ -52,13 +52,14 @@ from repro.cache import BlockManager
 from repro.configs.base import ModelConfig
 from repro.core.sampling import SamplingParams, sample
 from repro.models import PackedBatch, build_model
+from repro.models.blocks import POOL_KEY
 from repro.models.registry import Model
 
 # paged block-pool leaves (repro.models.blocks.init_paged_attn_cache) are
 # block-indexed, not slot-indexed: nothing to wipe on slot reuse — freed
 # blocks self-heal exactly like dense KV rows (overwritten before visible,
 # or hidden by the context mask)
-_POOL_KEYS = frozenset({"pkv"})
+_POOL_KEYS = frozenset({POOL_KEY})
 
 
 def _leaf_kind(path):

@@ -24,6 +24,7 @@ the chunk and decode segments.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Dict, Optional, Tuple
@@ -74,10 +75,42 @@ def init_attn_cache(cfg: ModelConfig, rows: int, max_len: int, dtype) -> Dict:
     return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}
 
 
+# the paged pool leaf's key: it marks the fused block layout, so the layer
+# scan, the packed path and the engine's block copies dispatch on it
+POOL_KEY = "pkv"
+_LANES = 128          # lanes of a TPU vector register: a tile's minor axis
+
+
+def paged_block_shape(n_kv_heads: int, block_size: int,
+                      head_dim: int) -> Tuple[int, ...]:
+    """Stored shape of one block of the fused paged pool.
+
+    ``[nk, 2, bs, hd]``, unless ``hd`` is no multiple of 128 lanes while a
+    ``[bs, hd]`` page is: then ``[nk, 2, bs * hd // 128, 128]``, the same
+    bytes in row-major order with 128 // hd tokens to a lane row.  A page
+    of half-empty lanes (``hd`` = 64) makes the TPU lay the pool out with
+    its block axis minor, so every block gather or scatter in the layer
+    scan needs the whole stacked pool copied into a padded layout and
+    back, twice the pool's bytes each step.  Folded, the pool keeps the
+    default layout with no padding.  :func:`unfold_blocks` undoes the
+    fold on gathered blocks."""
+    if head_dim % _LANES and (block_size * head_dim) % _LANES == 0:
+        return (n_kv_heads, 2, block_size * head_dim // _LANES, _LANES)
+    return (n_kv_heads, 2, block_size, head_dim)
+
+
+def unfold_blocks(blocks, head_dim: int):
+    """Stored pool blocks ``[..., nk, 2, r, w]`` -> ``[..., nk, 2, bs,
+    hd]`` (a reshape: :func:`paged_block_shape` keeps row-major order)."""
+    r, w = blocks.shape[-2:]
+    return blocks.reshape(blocks.shape[:-2] + (r * w // head_dim, head_dim))
+
+
 def init_paged_attn_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
                           dtype) -> Dict:
     """Pooled KV for full-attention layers: ONE fused leaf ``[n_blocks,
-    nk, 2, block_size, hd]`` (K at pair index 0, V at 1), addressed
+    nk, 2, block_size, hd]`` (K at pair index 0, V at 1; stored as
+    :func:`paged_block_shape` says), addressed
     through per-request block tables (``repro.cache``).  One leaf instead
     of split ``pk``/``pv`` halves the block-table DMA count in the Pallas
     kernels and halves the gather/scatter count on copy-on-write forks.
@@ -86,8 +119,9 @@ def init_paged_attn_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
     two axes) and TP splits the head axis without cutting a pair.  The
     key ``pkv`` (vs dense ``k``/``v``) marks the layout, so the packed
     path and the engine's slot reset dispatch structurally."""
-    shp = (n_blocks, cfg.n_kv_heads, 2, block_size, cfg.head_dim)
-    return {"pkv": jnp.zeros(shp, dtype)}
+    shp = (n_blocks,) + paged_block_shape(cfg.n_kv_heads, block_size,
+                                          cfg.head_dim)
+    return {POOL_KEY: jnp.zeros(shp, dtype)}
 
 
 def init_swa_cache(cfg: ModelConfig, rows: int, window: int, dtype) -> Dict:
@@ -228,69 +262,171 @@ def _shard_map_heads(fn, mesh, n_table_args):
         out_specs=P(None, "model", None), check_vma=False)
 
 
-def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
-    """Block-table variant of the full-attention packed path: KV written
-    through ONE (physical block, offset) scatter of the fused [nk, 2, hd]
-    token rows, read either via a fused-row gather + K/V split (XLA
-    backend) or the fused-pool paged Pallas kernels."""
-    C, D = pk.num_chunk, pk.num_decode
-    pool_kv = cache["pkv"]
-    bs = pool_kv.shape[3]
+@dataclasses.dataclass(frozen=True)
+class LayerPool:
+    """One layer's fused paged pool as the packed path addresses it.
+
+    ``pool`` is a pool leaf as stored (:func:`paged_block_shape`) with its
+    leading axes merged, ``[N', nk, 2, r, w]``, and the layer's blocks are
+    ``pool[base:base + n_blocks]``: inside the layer scan ``pool`` is the
+    whole stacked pool the scan carries and ``base`` the layer's first
+    block; a tail layer's own pool has ``base`` 0.  Reads gather and
+    writes scatter whole blocks at ``base + block``, so no op takes a
+    layer's pool out of the stack or writes one back.  Not a pytree: it
+    lives inside one traced layer (:func:`carry_in`, :func:`carry_out`)."""
+    pool: jax.Array
+    base: jax.Array | int
+    n_blocks: int
+
+    def block_size(self, hd: int) -> int:
+        return self.pool.shape[-2] * self.pool.shape[-1] // hd
+
+    def blocks(self, idx, hd: int):
+        """Blocks ``idx`` of this layer, ``[*idx.shape, nk, 2, bs, hd]``."""
+        return unfold_blocks(self.pool[self.base + idx], hd)
+
+    def rows(self, tables, hd: int):
+        """Per-token rows through block ``tables`` [..., M] ->
+        ``[..., M * bs, nk, 2, hd]``, as :func:`common.gather_block_rows`
+        reads a layer's own pool."""
+        return cm.block_rows(self.blocks(jnp.asarray(tables, jnp.int32), hd))
+
+    def whole(self, hd: int):
+        """This layer's pool ``[N, nk, 2, bs, hd]`` (the Pallas kernels'
+        operand): a read-only slice, or the pool itself for a tail layer."""
+        pool = jax.lax.dynamic_slice_in_dim(self.pool, self.base,
+                                            self.n_blocks)
+        return unfold_blocks(pool, hd)
+
+    def write_rows(self, phys, blk, off, kv) -> "LayerPool":
+        """Block read-modify-write: gather blocks ``phys`` [B], store the
+        token rows ``kv`` [T, nk, 2, hd] at (``blk`` [T] into ``phys``,
+        offset ``off`` [T]), scatter the whole blocks back.  Only the
+        scratch block 0 may repeat in ``phys``."""
+        at = self.base + phys
+        blocks = self.blocks(phys, kv.shape[-1]).at[blk, :, :, off].set(kv)
+        blocks = blocks.reshape(blocks.shape[:1] + self.pool.shape[1:])
+        return dataclasses.replace(self, pool=self.pool.at[at].set(blocks))
+
+
+def _is_pool(path) -> bool:
+    return bool(path) and getattr(path[-1], "key", None) == POOL_KEY
+
+
+def carry_in(path, leaf, layer=None):
+    """What a layer gets of the cache leaf at ``path``: layer ``layer`` of
+    a leaf stacked over the scanned layers, or a tail layer's own leaf
+    (``layer`` None).  The paged pool leaf comes as a :class:`LayerPool`
+    over the whole leaf: slicing a layer's pool out and writing it back
+    makes the TPU compiler copy and re-lay out that pool on every layer.
+    Every other leaf (dense ``k``/``v``, window rings, recurrent and SSD
+    state) is sliced."""
+    if _is_pool(path):
+        if layer is None:
+            return LayerPool(leaf, 0, leaf.shape[0])
+        L, N = leaf.shape[:2]
+        return LayerPool(leaf.reshape((L * N,) + leaf.shape[2:]),
+                         layer * N, N)
+    if layer is None:
+        return leaf
+    return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
+def carry_out(leaf, new, layer=None):
+    """Inverse of :func:`carry_in`: the leaf with the layer's update
+    ``new`` in it (the returned pool as it stands, a slice written back
+    at ``layer``, or a tail layer's new leaf)."""
+    if isinstance(new, LayerPool):
+        return new.pool.reshape(leaf.shape)
+    if layer is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, layer, 0)
+
+
+def _write_chunk(ref: LayerPool, pk: PackedBatch, kv) -> LayerPool:
+    """Write the chunk's token rows ``kv`` [C, nk, 2, hd] block by block.
+
+    C consecutive positions touch at most ``(C + 2bs - 2) // bs`` logical
+    blocks from ``chunk_start // bs``.  A block with no valid token (past
+    ``chunk_len`` or past ``max_len``) is routed to the scratch block 0,
+    so only the request's own live blocks are rewritten; rows past
+    ``chunk_len`` inside a live block are padding that a later write
+    overwrites before any query sees it."""
+    C = kv.shape[0]
+    bs = ref.block_size(kv.shape[-1])
     M = pk.chunk_blocks.shape[0]
-    use_pallas = _paged_attn_backend() == "pallas"
-    if use_pallas:
-        from repro.kernels import ops as kops
-        mesh = _paged_shard_mesh(pool_kv)
+    first = pk.chunk_start // bs
+    lb = first + jnp.arange((C + 2 * bs - 2) // bs, dtype=jnp.int32)
+    live = (lb < M) & (lb * bs < pk.chunk_start + pk.chunk_len)
+    phys = jnp.where(live, pk.chunk_blocks[jnp.clip(lb, 0, M - 1)], 0)
+    cpos = pk.chunk_start + jnp.arange(C, dtype=jnp.int32)
+    return ref.write_rows(phys, cpos // bs - first, cpos % bs, kv)
+
+
+def _write_decodes(ref: LayerPool, pk: PackedBatch, kv) -> LayerPool:
+    """Write each decode lane's row ``kv`` [D, nk, 2, hd] into its block
+    at ``decode_ctx % bs``; padding lanes' tables name only block 0."""
+    bs = ref.block_size(kv.shape[-1])
+    bidx = (pk.decode_ctx // bs)[:, None]
+    phys = jnp.take_along_axis(pk.decode_blocks, bidx, axis=1)[:, 0]
+    lanes = jnp.arange(kv.shape[0], dtype=jnp.int32)
+    return ref.write_rows(phys, lanes, pk.decode_ctx % bs, kv)
+
+
+def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
+    """Block-table variant of the full-attention packed path.  The fused
+    [nk, 2, hd] token rows are written first, the chunk's and then the
+    decodes', by whole-block read-modify-writes; then the chunk and the
+    decodes read, either via a fused-row gather + K/V split (XLA backend)
+    or the fused-pool paged Pallas kernels.  Neither read can see the
+    other segment's writes except in the scratch block: a decode writes
+    only its own (copy-on-write forked) last block.  ``cache["pkv"]`` is
+    a :class:`LayerPool` and so is the result's."""
+    C, D = pk.num_chunk, pk.num_decode
+    hd = cfg.head_dim
+    ref = cache[POOL_KEY]
+    with jax.named_scope("kv_write"):
+        if C:
+            ref = _write_chunk(ref, pk, cm.fuse_kv(k[:C], v[:C]))
+        if D:
+            ref = _write_decodes(ref, pk, cm.fuse_kv(k[C:], v[C:]))
     outs = []
-    if C:
-        cpos = pos[:C]
-        # padding lanes past max_len must NOT clamp into the table's last
-        # (live) block — route them to the reserved scratch block instead
-        with jax.named_scope("kv_write"):
-            bidx = cpos // bs
-            phys = jnp.where(bidx < M,
-                             pk.chunk_blocks[jnp.clip(bidx, 0, M - 1)], 0)
-            pool_kv = pool_kv.at[phys, :, :, cpos % bs].set(
-                cm.fuse_kv(k[:C], v[:C]))
-        if use_pallas:
+    if _paged_attn_backend() == "pallas":
+        from repro.kernels import ops as kops
+        with jax.named_scope("kv_read"):
+            pool = ref.whole(hd)
+        mesh = _paged_shard_mesh(pool)
+        if C:
             bq = 128 if C % 128 == 0 else C
             call = functools.partial(kops.paged_chunked_prefill_attention,
                                      bq=bq)
             if mesh is not None:
                 call = _shard_map_heads(call, mesh, n_table_args=2)
             with jax.named_scope("attn"):
-                out_c = call(q[:C], pool_kv, pk.chunk_blocks,
-                             pk.chunk_start)
-        else:
-            with jax.named_scope("kv_read"):
-                rows = cm.gather_block_rows(pool_kv, pk.chunk_blocks)
-                row_k, row_v = cm.split_fused_kv(rows)
-            with jax.named_scope("attn"):
-                out_c = cm.blocked_gqa_attention(
-                    q[None, :C], row_k[None], row_v[None], cpos[None])[0]
-        outs.append(out_c)
-    if D:
-        with jax.named_scope("kv_write"):
-            bidx = (pk.decode_ctx // bs)[:, None]
-            phys = jnp.take_along_axis(pk.decode_blocks, bidx, axis=1)[:, 0]
-            pool_kv = pool_kv.at[phys, :, :, pk.decode_ctx % bs].set(
-                cm.fuse_kv(k[C:], v[C:]))
-        if use_pallas:
+                outs.append(call(q[:C], pool, pk.chunk_blocks,
+                                 pk.chunk_start))
+        if D:
             call = kops.paged_decode_attention
             if mesh is not None:
                 call = _shard_map_heads(call, mesh, n_table_args=2)
             with jax.named_scope("attn"):
-                out_d = call(q[C:], pool_kv, pk.decode_blocks, pk.decode_ctx)
-        else:
+                outs.append(call(q[C:], pool, pk.decode_blocks,
+                                 pk.decode_ctx))
+    else:
+        if C:
             with jax.named_scope("kv_read"):
-                rows = cm.gather_block_rows(pool_kv, pk.decode_blocks)
-                gk, gv = cm.split_fused_kv(rows)
+                row_k, row_v = cm.split_fused_kv(ref.rows(pk.chunk_blocks, hd))
             with jax.named_scope("attn"):
-                out_d = cm.blocked_gqa_attention(
-                    q[C:, None], gk, gv, pk.decode_ctx[:, None])[:, 0]
-        outs.append(out_d)
+                outs.append(cm.blocked_gqa_attention(
+                    q[None, :C], row_k[None], row_v[None], pos[None, :C])[0])
+        if D:
+            with jax.named_scope("kv_read"):
+                gk, gv = cm.split_fused_kv(ref.rows(pk.decode_blocks, hd))
+            with jax.named_scope("attn"):
+                outs.append(cm.blocked_gqa_attention(
+                    q[C:, None], gk, gv, pk.decode_ctx[:, None])[:, 0])
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-    return out, {"pkv": pool_kv}
+    return out, {POOL_KEY: ref}
 
 
 def attn_packed(cfg, p, x, cache, pk: PackedBatch,
@@ -304,7 +440,7 @@ def attn_packed(cfg, p, x, cache, pk: PackedBatch,
         q = cm.apply_rope(q, sin, cos)
         k = cm.apply_rope(k, sin, cos)
 
-    if "pkv" in cache:
+    if POOL_KEY in cache:
         assert window is None, "window caches are slot-indexed, not paged"
         out, new_cache = _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk)
         with jax.named_scope("o_proj"):

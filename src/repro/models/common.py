@@ -242,9 +242,14 @@ def gather_block_rows(pool, block_tables):
     x tables [..., M] -> [..., M * bs, nk, 2, hd] in logical-position
     order (shared by the packed paged attention path and the kernel
     oracles); :func:`split_fused_kv` then yields the K and V rows."""
-    bt = jnp.asarray(block_tables, jnp.int32)
-    rows = jnp.moveaxis(pool[bt], -2, -4)        # [..., M, bs, nk, 2, hd]
-    return rows.reshape(bt.shape[:-1] + (bt.shape[-1] * pool.shape[3],)
+    return block_rows(pool[jnp.asarray(block_tables, jnp.int32)])
+
+
+def block_rows(blocks):
+    """Gathered pool blocks [..., M, nk, 2, bs, hd] -> per-token rows
+    [..., M * bs, nk, 2, hd] in logical-position order."""
+    rows = jnp.moveaxis(blocks, -2, -4)          # [..., M, bs, nk, 2, hd]
+    return rows.reshape(rows.shape[:-5] + (rows.shape[-5] * rows.shape[-4],)
                         + rows.shape[-3:])
 
 

@@ -419,12 +419,19 @@ def _scan_unroll() -> int | bool:
 
 def _scan_cached_groups(group_kinds, apply_fn, x, aux, params_groups,
                         cache_groups, *, remat: bool = False):
-    """Scan the grouped layers with the stacked cache in the carry: each
-    step reads its group's cache slice and writes the updated slice back
-    into the stacked leaves, which XLA updates in place.  Scanning the
-    cache as xs -> ys instead allocates the outputs as a second whole
-    cache (a second KV pool's worth of device memory).  Returns
-    (x, aux, new_cache_groups)."""
+    """Scan the grouped layers with the stacked cache in the carry.
+
+    Each layer takes its cache from the stacked leaves through
+    :func:`~repro.models.blocks.carry_in` and puts it back through
+    :func:`~repro.models.blocks.carry_out`: the paged pool leaf never
+    leaves the stack (the layer reads and writes whole blocks at its
+    block offset in it, and the returned stack goes straight back into
+    the carry); every other leaf (dense ``k``/``v``, window rings,
+    recurrent and SSD state) is sliced with ``dynamic_index_in_dim`` and
+    written back with ``dynamic_update_index_in_dim``, which the TPU
+    compiler may copy and re-lay out.  Scanning the cache as xs -> ys
+    instead allocates the outputs as a second whole cache (a second KV
+    pool's worth of device memory).  Returns (x, aux, new_cache_groups)."""
     n = jax.tree.leaves(params_groups)[0].shape[0]
 
     def group_body(carry, xs):
@@ -432,18 +439,16 @@ def _scan_cached_groups(group_kinds, apply_fn, x, aux, params_groups,
         gp, i = xs
         x = _constrain_cache_act(x)
         with jax.named_scope("kv_carry"):
-            gc = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
-                gcs)
+            gc = jax.tree_util.tree_map_with_path(
+                lambda path, a: bk.carry_in(path, a, i), gcs)
         new_gc = []
         for j, kind in enumerate(group_kinds):
             x, nc, a = apply_fn(kind, gp[j], gc[j], x)
             new_gc.append(nc)
             aux = aux + a
         with jax.named_scope("kv_carry"):
-            gcs = jax.tree.map(
-                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
-                gcs, new_gc)
+            gcs = jax.tree.map(lambda a, u: bk.carry_out(a, u, i),
+                               gcs, new_gc)
         return (x, aux, gcs), None
 
     body = jax.checkpoint(group_body, prevent_cse=False) if remat else group_body
@@ -567,8 +572,11 @@ def forward_packed_stage(cfg: ModelConfig, params, pk: PackedBatch, cache,
     if "tail" in cache:
         new_tail = []
         for j, kind in enumerate(tail_kinds):
-            x, nc, a = apply_fn(kind, params["tail"][j], cache["tail"][j], x)
-            new_tail.append(nc)
+            tc = cache["tail"][j]
+            x, nc, a = apply_fn(
+                kind, params["tail"][j],
+                jax.tree_util.tree_map_with_path(bk.carry_in, tc), x)
+            new_tail.append(jax.tree.map(bk.carry_out, tc, nc))
             aux = aux + a
         new_cache["tail"] = new_tail
     if not last:

@@ -284,7 +284,7 @@ def cache_pspecs(cfg: ModelConfig, shapes, *,
             if kv_mode in ("seq", "hd") and div(shp[-1]):
                 return spec(rspec, None, None, MDL)
             return spec(rspec, None, None, None)
-        if name == "pkv":                   # fused pool [N, nk, 2, bs, hd]
+        if name == "pkv":    # fused pool [N, nk, 2, bs, hd] (or r, 128)
             if div(shp[-4]):
                 return spec(None, MDL, None, None, None)
             if kv_mode == "seq" and div(shp[-5]):

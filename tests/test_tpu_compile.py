@@ -12,8 +12,11 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and each test worker imports every
 test file.
 """
+import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +113,99 @@ def test_named_scopes_change_no_tpu_program(topo, monkeypatch):
     from test_obs import assert_scopes_change_only_metadata
     assert_scopes_change_only_metadata(
         monkeypatch, SingleDeviceSharding(topo.devices[0]))
+
+
+# an instruction's name, output shape and opcode in compiled HLO text
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$",
+    re.M)
+# what may output a pool-shaped array without moving the pool: values
+# passed along, and the whole-block scatters into the stack
+_PASS = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def pool_shaped_movers(hlo: str, n_blocks, block_dims, n_layers) -> list:
+    """Instructions with an output of a pool's shape (a layer's pool
+    ``[n_blocks, *block_dims]``, the stack of them, or the stack with its
+    layer and block axes merged) that are neither a pass-through nor a
+    scatter (a ``scatter`` or a fusion XLA made of one): copies,
+    relayouts, slices and write-backs of whole pools."""
+    tail = "," + ",".join(map(str, block_dims))
+    heads = {f",{n_blocks}", f",{n_layers * n_blocks}"}
+    out = []
+    for name, shape, opcode, rest in _INSTR.findall(hlo):
+        shape = "," + shape
+        if (not any(shape.endswith(h + tail) for h in heads)
+                or opcode in _PASS):
+            continue
+        op = re.search(r'op_name="([^"]*)"', rest)
+        if opcode == "scatter" or (opcode == "fusion" and op
+                                   and op.group(1).endswith("/scatter")):
+            continue
+        out.append(f"{name} {opcode} [{shape[1:]}]")
+    return out
+
+
+def _compiled_steps(topo, cfg, n_blocks):
+    """Both packed steps (hybrid, decode-only) of an engine serving ``cfg``
+    (8 slots of up to 1,024 tokens, blocks of 16, a pool of ``n_blocks``),
+    compiled for one chip of the described v5e; and the pool's shape."""
+    from repro.core.engine import DecodeWork, Engine
+    from repro.models import build_model
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda: model.init_params(
+        jax.random.PRNGKey(0), DTYPE))
+    eng = Engine(cfg, params, n_slots=8, max_len=1024, chunk_size=CHUNK,
+                 decode_slots=7, dtype=DTYPE, paged=True, block_size=16)
+    cache = jax.eval_shape(lambda: model.init_cache(
+        9, 1024, DTYPE, paged_blocks=n_blocks, block_size=16))
+    eng.add_request(7)
+    one = SingleDeviceSharding(topo.devices[0])
+    steps = []
+    for pad_chunk in (True, False):
+        pk = eng._pack(None, [DecodeWork(7, 3, 5)], pad_chunk=pad_chunk)
+        args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), (params, pk, cache, eng._key))
+        steps.append(eng._step.lower(*args).compile())
+    pool, = [a for a in jax.tree.leaves(cache) if a.ndim == 6]
+    return steps, pool.shape
+
+
+def test_layer_scan_moves_no_whole_pool(topo):
+    """Both packed steps of an engine at Mistral-7B widths (2 layers, 513
+    blocks of 16) compile, for a v5e, with no copy, slice or write-back
+    of a layer's pool or of the stacked pool: the scan hands each layer
+    the stack and writes whole blocks at the layer's block offset."""
+    from repro.configs import get_config
+    cfg = dataclasses.replace(
+        get_config("tinyllama-1.1b"), n_layers=2, d_model=4096, n_heads=32,
+        n_kv_heads=8, head_dim=128, d_ff=14336, vocab_size=32768,
+        rope_theta=1e6)
+    steps, shape = _compiled_steps(topo, cfg, 513)
+    assert shape == (2, 513, NK, 2, 16, HD)
+    for step in steps:
+        hlo = step.as_text()
+        assert pool_shaped_movers(hlo, 513, (NK, 2, 16, HD), 2) == []
+        assert re.search(r"= bf16\[1026,8,2,16,128\]\S* scatter\(", hlo)
+
+
+def test_layer_scan_keeps_a_64_wide_pool_in_place(topo):
+    """At Qwen2-0.5B widths (``hd`` = 64; 2 layers, a pool of 16,385
+    blocks of 16) the pool is stored in 128-lane rows, and neither step
+    copies it: no pool-shaped instruction but pass-throughs and scatters,
+    and the step's temporaries stay under half the pool.  A ``[16, 64]``
+    page would have the compiler lay the stack out with its block axis
+    minor and copy all of it into a padded layout and back each step
+    (temporaries about twice the pool)."""
+    from repro.configs import get_config
+    cfg = dataclasses.replace(get_config("qwen2-0.5b"), n_layers=2,
+                              vocab_size=32768)
+    assert cfg.head_dim == 64
+    block = bk.paged_block_shape(cfg.n_kv_heads, 16, cfg.head_dim)
+    assert block == (cfg.n_kv_heads, 2, 8, 128)
+    steps, shape = _compiled_steps(topo, cfg, 16385)
+    assert shape == (2, 16385) + block
+    pool_bytes = math.prod(shape) * 2
+    for step in steps:
+        assert pool_shaped_movers(step.as_text(), 16385, block, 2) == []
+        assert step.memory_analysis().temp_size_in_bytes < pool_bytes // 2
