@@ -15,8 +15,9 @@ device operation (``chipbench/program.py``), and standard error gets:
 * ``device by scope``: device seconds summed per named scope;
 * the top operations with their scopes and the longest idle gaps with
   their labels;
-* ``pack_ms_per_step``, ``idle_on_host_share``, ``kv_move_share`` and,
-  where the cell has the requests, ``queue_wait_ms``.
+* ``pack_ms_per_step``, ``idle_on_host_share`` and, where the cell has
+  the requests, ``queue_wait_ms`` (the result line's ``metrics`` carry
+  the cell's per-layer metrics, ``kv_move_share`` among them).
 
 Either way it prints the Python garbage collector's pauses in the window
 and the longest engine calls, to tell a stall of the host from one of the
@@ -121,33 +122,20 @@ def main(argv=None) -> int:
     # would run, and be read, without them
     jax.config.update("jax_enable_compilation_cache", False)
     from chipbench import harness, program
-    from chipbench import trace as trace_mod
-    from chipbench.driver import clock
 
-    # harness.run reads the trace with trace.load and deletes it; read the
-    # program's part of the same file on the way
     seen = {}
-    load = trace_mod.load
 
-    def load_both(path):
-        t = clock()
-        # the engine is still alive while harness.run reads the trace
-        scopes = program.hlo_scopes(program.step_hlo(rec.engine))
-        seen["program"] = program.load(path, scopes)
-        seen["trace"] = tr = load(path)
-        harness.say(f"program spans and scopes read in {clock() - t:.2f} s")
-        return tr
+    def on_trace(tr, prog, red):
+        seen.update(trace=tr, program=prog, reduced=red)
 
-    trace_mod.load = load_both
     rec = Recorder()
     gc.callbacks.append(rec.on_gc)
     try:
         out = harness.run(cell, args.seed, args.seconds, bool(args.trace),
                           t_proc=T_PROC, devices=devices[:cell.chips],
-                          server_hook=rec.hook)
+                          server_hook=rec.hook, on_trace=on_trace)
     finally:
         gc.callbacks.remove(rec.on_gc)
-        trace_mod.load = load
 
     # requests arrive at ``arrival_time`` on the traffic's clock, which
     # starts at the host-clock time submission less arrival reads least;
@@ -159,17 +147,15 @@ def main(argv=None) -> int:
     start = min(t - r.arrival_time for t, r in timed)
     extra = host_report(rec, start + w0, start + w0 + args.seconds,
                         harness.say)
-    if args.trace and "program" in seen:
-        tr, prog = seen["trace"], seen["program"]
-        red = trace_mod.reduce(tr)
+    if seen.get("reduced") is not None:
+        tr, prog, red = seen["trace"], seen["program"], seen["reduced"]
         gaps = program.labelled_gaps(tr, prog, red)
         idle = program.idle_by_label(gaps, red.chips)
         scopes = program.device_by_scope(prog, red.window)
         ops = program.top_ops(prog, red.window)
         rel.update(
             pack_ms_per_step=program.pack_ms_per_step(tr, prog, red.window),
-            idle_on_host_share=program.idle_on_host_share(prog, red),
-            kv_move_share=program.kv_move_share(prog, red))
+            idle_on_host_share=program.idle_on_host_share(prog, red))
         harness.say(f"program spans: {len(prog.host)}")
         harness.say("idle by label (s): " + ", ".join(
             f"{k} {v!r}" for k, v in idle.items()))

@@ -14,6 +14,12 @@ Set-up (``setup_s``, from process start until the window opens):
    is in its steady state when the window opens.
 
 No knob of the program is set: every cell runs its defaults.
+
+A traced run profiles the whole window.  After it, while the engine is
+alive, the step's two shapes are traced for their named scopes and
+compiled for their HLO text (``program.step_scopes``), so the readers get
+device time by scope and by operation beside the trace's busy time
+(``measure.Reading``).
 """
 from __future__ import annotations
 
@@ -23,7 +29,7 @@ import sys
 import tempfile
 from typing import Callable, Optional
 
-from chipbench import check, measure, spec, traffic, weights
+from chipbench import check, counts, measure, program, spec, traffic, weights
 from chipbench import trace as trace_mod
 from chipbench.driver import Driver, clock
 
@@ -93,8 +99,8 @@ def build(cell: spec.Cell, seed: int, devices,
 
     hf = cell.config
     geo = cell.cell["engine"]
-    cfg = spec.program_config(hf)
     ref = cell.reference()
+    cfg = ref.program_config(hf)
     t = clock()
     template = jax.eval_shape(
         lambda k: build_model(cfg).init_params(k, jnp.bfloat16),
@@ -130,13 +136,16 @@ def build(cell: spec.Cell, seed: int, devices,
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         t_proc: float, devices, peaks: Optional[dict] = None,
         server_hook: Optional[Callable] = None,
+        on_trace: Optional[Callable] = None,
         control: bool = False) -> dict:
     """Serve the cell once and return the result line's object.
 
     ``server_hook(server)`` is called on the built server before warm-up
-    (tests use it to break the timed path).  With ``control`` the check
-    compares the control (the reference one precision step lower) in the
-    program's place, and has to come out not correct."""
+    (tests use it to break the timed path).  A traced run hands
+    ``on_trace(trace, program_trace, reduced)`` what it read of the trace.
+    With ``control`` the check compares the control (the reference one
+    precision step lower) in the program's place, and has to come out not
+    correct."""
     import jax
     dev = devices[0]
     if peaks is None:
@@ -184,20 +193,30 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     say(f"compiles inside the window: {compiles.n} {compiles.names[:5]}")
     say(f"memory_peak_bytes={mem}; setup_s={setup_s!r}")
 
-    reading = measure.Reading(hf=hf, decode_lanes=eng.D, chips=cell.chips,
+    reading = measure.Reading(hf=hf, terms=counts.Terms(**ref.step_terms(hf)),
+                              decode_lanes=eng.D, chips=cell.chips,
                               peaks=peaks, steps=steps)
     red = None
     if trace:
         t = clock()
-        tr = trace_mod.load(trace_mod.find_xplane(trace_dir))
+        # the engine is still alive: its steps' scopes, compiled afresh
+        scopes = program.step_scopes(eng)
+        path = trace_mod.find_xplane(trace_dir)
+        tr = trace_mod.load(path)
+        prog = program.load(path, scopes)
         shutil.rmtree(trace_dir, ignore_errors=True)
         red = trace_mod.reduce(tr)
         reading.traced = red
         reading.spans = trace_mod.step_spans(tr)
         if red is not None:
+            reading.scope_s = program.device_by_scope(prog, red.window)
+            reading.ops = program.op_times(prog, red.window)
             say(f"trace: {red.chips} chip(s), window {red.window_s!r} s, "
                 f"busy {red.busy_s!r} s, {len(reading.traced_steps())} "
-                f"traced steps; read in {clock() - t:.2f} s")
+                f"traced steps, {len(set(scopes.values()))} scopes; "
+                f"read in {clock() - t:.2f} s")
+        if on_trace is not None:
+            on_trace(tr, prog, red)
 
     # the check: state freed first, so the reference sets no peak
     limits = cell.cell["check"]
@@ -211,8 +230,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                         p_pad=cell.traffic["output"]["max"])
     gaps = [R.gaps(g) for g in sample]
     if control:
-        program = max((float(x.max()) for x in gaps if len(x)), default=None)
-        say(f"program's logit_gap_max beside the control: {program!r}")
+        served = max((float(x.max()) for x in gaps if len(x)), default=None)
+        say(f"program's logit_gap_max beside the control: {served!r}")
         gaps = [R.control_gaps(g) for g in sample]
     R.free()
     n_tok = int(sum(len(x) for x in gaps))

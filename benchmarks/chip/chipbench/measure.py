@@ -8,15 +8,17 @@ first token by ``w1`` counts with the time it has waited until then.
   request due in the window;
 * ``ttft_per_ktok_ms``: the TTFTs of those requests summed, over their
   prompt tokens summed, per thousand prompt tokens;
-* ``tbt_p50_ms``, ``tbt_p99_ms``: percentiles of every gap between two
-  consecutive tokens of one request, both inside the window;
+* ``tbt_p50_ms``, ``tbt_p90_ms``, ``tbt_p99_ms``: percentiles of every
+  gap between two consecutive tokens of one request, both inside the
+  window;
 * ``out_tok_s``: tokens returned inside the window over its length.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
+from chipbench.counts import Terms
 from chipbench.driver import Log, Step
 from chipbench.traffic import percentile
 
@@ -68,6 +70,7 @@ def end_to_end(logs: Dict[int, Log], w0: float, w1: float,
     gaps = tbts(logs, w0, w1)
     if gaps:
         out["tbt_p50_ms"] = percentile(gaps, 50) * 1e3
+        out["tbt_p90_ms"] = percentile(gaps, 90) * 1e3
         out["tbt_p99_ms"] = percentile(gaps, 99) * 1e3
     return out
 
@@ -84,6 +87,7 @@ def failures(logs: Dict[int, Log], w0: float, w1: float) -> int:
 class Reading:
     """What a per-layer metric's reader is given."""
     hf: dict                        # the configuration file
+    terms: Terms                    # its reference's costs (counts.Terms)
     decode_lanes: int               # the engine's D
     chips: int
     peaks: dict                     # peaks.json entry of this device
@@ -91,6 +95,20 @@ class Reading:
     traced: Optional[object] = None     # trace.Reduced, traced runs only
     # step index -> {span kind: (start_ns, end_ns)} on the trace's clock
     spans: Dict[int, Dict[str, tuple]] = field(default_factory=dict)
+    # traced runs: device seconds in the traced window per scope path of
+    # the packed step's named scopes, outermost first ('kv_read',
+    # 'kv_read/gather'; 'none' for operations outside every scope), and
+    # (operation, scope path or '', seconds) of every operation, longest
+    # first; both mean over the chips (chipbench.program)
+    scope_s: Dict[str, float] = field(default_factory=dict)
+    ops: List[Tuple[str, str, float]] = field(default_factory=list)
+
+    def under(self, scopes) -> float:
+        """Device seconds of the traced operations under any of ``scopes``,
+        at any depth of their scope path."""
+        want = set(scopes)
+        return sum(s for path, s in self.scope_s.items()
+                   if want & set(path.split("/")))
 
     def traced_steps(self) -> List[Step]:
         """Steps whose execute span is inside the traced window."""

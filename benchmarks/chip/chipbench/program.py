@@ -7,10 +7,12 @@ on the same clock:
 * host spans ``repro.<layer>.<part>`` around the scheduler's and the
   engine's calls (``src/repro/obs.py``): ``repro.sched.next_plan``,
   ``repro.engine.pack``, ``repro.engine.collect``, ...;
-* a ``jax.named_scope`` on each part of the packed step (``SCOPES``),
-  which the compiler keeps in each operation's metadata (``op_name``).
-  A TPU trace's operation events carry the operation's HLO text but not
-  its metadata, so the scopes are read from the compiled step's HLO text
+* a ``jax.named_scope`` on each part of the packed step, which the
+  compiler keeps in each operation's metadata (``op_name``).  Which names
+  are scopes is read from the step's jaxpr (``named_scopes``), so a scope
+  the program adds is found without an edit here.  A TPU trace's
+  operation events carry the operation's HLO text but not its metadata,
+  so the scopes are read from the compiled step's HLO text
   (``hlo_scopes``) and matched to the events by operation name.
 
 This module reads both and reduces them, beside ``trace.reduce``:
@@ -18,10 +20,11 @@ This module reads both and reduces them, beside ``trace.reduce``:
 * idle gaps labelled by the innermost span of either kind open at the
   gap's middle, so a gap inside ``bench.execute#k`` and
   ``repro.engine.pack`` reads ``engine.pack``;
-* device seconds per scope, and the top operations with their scope as
-  a prefix (``kv_read|%fusion.229 = bf16[...]``);
-* the readings that need them: ``pack_ms_per_step``,
-  ``idle_on_host_share``, ``kv_move_share`` and ``queue_wait_ms``.
+* device seconds per scope path, every operation's time with its scope
+  path, and the top operations with their path as a prefix
+  (``kv_read|%fusion.229 = bf16[...]``);
+* the readings that need the host spans: ``pack_ms_per_step``,
+  ``idle_on_host_share`` and ``queue_wait_ms``.
 
 A trace without program spans or scopes (a program that has none) gives
 the labels ``trace.reduce`` gives, and readings of None.
@@ -31,15 +34,12 @@ from __future__ import annotations
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Collection, Dict, Iterable, List, Optional, Sequence,
+                    Set, Tuple)
 
 from chipbench import trace as tm
 
 PREFIX = "repro."
-SCOPES = ("embed", "qkv", "kv_write", "kv_read", "attn", "o_proj", "ffn",
-          "kv_carry", "unembed", "sample")
-KV_SCOPES = ("kv_read", "kv_write", "kv_carry")
-_SCOPE_RE = re.compile(r"(?:^|/)(" + "|".join(SCOPES) + r")(?=/|$)")
 
 Span = Tuple[int, int, str]
 
@@ -48,15 +48,35 @@ Span = Tuple[int, int, str]
 class Program:
     # [(start_ns, end_ns, span name)] of the program's ``repro.*`` spans
     host: List[Span] = field(default_factory=list)
-    # plane name -> [(start_ns, end_ns, op name, scope or "")]
+    # plane name -> [(start_ns, end_ns, op name, scope path or "")]
     device: Dict[str, List[Tuple[int, int, str, str]]] = field(
         default_factory=dict)
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost scope of ``SCOPES`` in an op name's path, or ''."""
-    found = _SCOPE_RE.findall(op_name or "")
-    return found[-1] if found else ""
+def scope_path(op_name: str, scopes: Collection[str]) -> str:
+    """The names of ``scopes`` on an op name's path, outermost first and
+    joined by '/' (``kv_read`` or ``kv_read/gather``), or ''.  A scope
+    nested inside another keeps the outer one on its path, so a reader of
+    the outer scope still counts it."""
+    return "/".join(c for c in (op_name or "").split("/") if c in scopes)
+
+
+def named_scopes(jaxpr) -> Set[str]:
+    """Every name on the name stack of an equation of ``jaxpr`` or of a
+    jaxpr nested in one (a scan's body, a branch, a kernel): the
+    ``jax.named_scope``s the traced function puts on its operations.
+    Names that are no identifier, such as the subscripts ``jnp.einsum``
+    names its call by, are JAX's own and left out."""
+    out: Set[str] = set()
+    for e in jaxpr.eqns:
+        out.update(s.name for s in e.source_info.name_stack.stack
+                   if s.name.isidentifier())
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out |= named_scopes(sub)
+    return out
 
 
 _INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*)$")
@@ -64,12 +84,14 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%([\w.\-]+)")
 
 
-def hlo_scopes(texts: Iterable[str]) -> Dict[str, str]:
-    """{op label: scope} of every scoped operation in the compiled
-    modules' HLO text (``Compiled.as_text()``).  An instruction takes its
-    own op name's scope; a fusion without one takes the scope most of the
-    instructions of the computation it calls have.  A label that two
-    modules give different scopes (or one none) is left out."""
+def hlo_scopes(texts: Iterable[str],
+               scopes: Collection[str]) -> Dict[str, str]:
+    """{op label: scope path} of every operation scoped by one of
+    ``scopes`` in the compiled modules' HLO text (``Compiled.as_text()``).
+    An instruction takes its own op name's path (``scope_path``); a
+    fusion without one takes the path most of the instructions of the
+    computation it calls have.  A label that two modules give different
+    paths (or one none) is left out."""
     out: Dict[str, str] = {}
     clash = set()
     for text in texts:
@@ -86,7 +108,7 @@ def hlo_scopes(texts: Iterable[str]) -> Dict[str, str]:
                 continue
             name, rest = m.groups()
             own = _OP_NAME.search(rest)
-            scope = scope_of(own.group(1)) if own else ""
+            scope = scope_path(own.group(1), scopes) if own else ""
             if scope:
                 called[comp].append(scope)
             calls = _CALLS.search(rest)
@@ -102,24 +124,28 @@ def hlo_scopes(texts: Iterable[str]) -> Dict[str, str]:
     return {k: v for k, v in out.items() if v and k not in clash}
 
 
-def step_hlo(engine) -> List[str]:
-    """Compiled HLO text of the engine's two packed-step shapes, hybrid
-    and decode-only.  Compiled afresh, they are the programs the engine
-    runs only where those were compiled afresh too: the persistent
-    compilation cache's key leaves metadata out, so a program cached from
-    code without the scopes comes back without them."""
-    out = []
+def step_scopes(engine) -> Dict[str, str]:
+    """{op label: scope path} of the engine's two packed-step shapes,
+    hybrid and decode-only, each traced for its named scopes and compiled
+    for its HLO text.  Compiled afresh, they are the programs the engine runs only
+    where those were compiled afresh too: the persistent compilation
+    cache's key leaves metadata out, so a program cached from code
+    without the scopes comes back without them."""
+    names: Set[str] = set()
+    texts = []
     for pad_chunk in (True, False):
         pk = engine._pack(None, [], pad_chunk=pad_chunk)
-        out.append(engine._step.lower(engine.params, pk, engine.cache,
-                                      engine._key).compile().as_text())
-    return out
+        traced = engine._step.trace(engine.params, pk, engine.cache,
+                                    engine._key)
+        names |= named_scopes(traced.jaxpr.jaxpr)
+        texts.append(traced.lower().compile().as_text())
+    return hlo_scopes(texts, names)
 
 
 def load(path: str, scopes: Optional[Dict[str, str]] = None) -> Program:
     """The program's spans and the chip's operations of a trace, each
-    operation with its scope from ``scopes`` (``hlo_scopes``), matched by
-    its label (name and shape)."""
+    operation with its scope path from ``scopes`` (``hlo_scopes``),
+    matched by its label (name and shape)."""
     import jax
     scopes = scopes or {}
     pd = jax.profiler.ProfileData.from_file(path)
@@ -199,7 +225,8 @@ def idle_by_label(gaps: Iterable[Tuple[str, float]],
 
 # ------------------------------------------------------------ operations
 def _leaf_times(prog: Program, window: tm.Interval):
-    """(scope, op label, ns inside the window) of every innermost op."""
+    """(scope path, op label, ns inside the window) of every innermost
+    op."""
     a, b = window
     for ops in prog.device.values():
         for s, e, i in tm.leaves([(s, e, i)
@@ -210,8 +237,8 @@ def _leaf_times(prog: Program, window: tm.Interval):
 
 
 def device_by_scope(prog: Program, window: tm.Interval) -> Dict[str, float]:
-    """Device seconds of innermost operations per scope ('none' for ops
-    without one), mean over chips, largest first."""
+    """Device seconds of innermost operations per scope path ('none' for
+    ops without one), mean over chips, largest first."""
     out: Dict[str, float] = defaultdict(float)
     for scope, _, ns in _leaf_times(prog, window):
         out[scope or "none"] += ns
@@ -223,13 +250,25 @@ def device_by_scope(prog: Program, window: tm.Interval) -> Dict[str, float]:
 def top_ops(prog: Program, window: tm.Interval,
             top: int = 10) -> List[Tuple[str, float]]:
     """``trace.reduce``'s top operations, each label prefixed by its scope
-    (``scope|label``) where the op has one."""
+    path (``path|label``) where the op has one."""
     out: Dict[str, float] = defaultdict(float)
     for scope, label, ns in _leaf_times(prog, window):
         out[f"{scope}|{label}" if scope else label] += ns
     chips = max(len(prog.device), 1)
     return [(n, t * 1e-9 / chips)
             for n, t in sorted(out.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def op_times(prog: Program,
+             window: tm.Interval) -> List[Tuple[str, str, float]]:
+    """(op label, scope path or '', seconds) of every innermost operation
+    in the window, summed per label, mean over chips, longest first."""
+    out: Dict[Tuple[str, str], float] = defaultdict(float)
+    for scope, label, ns in _leaf_times(prog, window):
+        out[label, scope] += ns
+    chips = max(len(prog.device), 1)
+    return [(label, scope, t * 1e-9 / chips) for (label, scope), t
+            in sorted(out.items(), key=lambda kv: -kv[1])]
 
 
 # -------------------------------------------------------------- readings
@@ -259,15 +298,6 @@ def idle_on_host_share(prog: Program, red: tm.Reduced) -> Optional[float]:
         return None
     idle = sum((e - s) - red.device_ns(s, e) for s, e in spans)
     return 100.0 * idle / (b - a)
-
-
-def kv_move_share(prog: Program, red: tm.Reduced) -> Optional[float]:
-    """Device time of the operations under ``kv_read``, ``kv_write`` and
-    ``kv_carry`` over the device's busy time."""
-    by_scope = device_by_scope(prog, red.window)
-    if set(by_scope) <= {"none"} or red.busy_ns <= 0:
-        return None
-    return 100.0 * sum(by_scope.get(s, 0.0) for s in KV_SCOPES) / red.busy_s
 
 
 def queue_wait_ms(requests: Iterable, w0: float,

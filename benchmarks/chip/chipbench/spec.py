@@ -5,7 +5,18 @@ metrics.  Everything that belongs to one of them sits in a file of its own
 under this benchmark's directory:
 
 * a configuration: the file its entry names (``configs/<name>.json``), and
-  the plain reference that file names (``references/<reference>.py``);
+  the plain reference that file names (``references/<reference>.py``).
+  The reference is all the benchmark knows of an architecture; it
+  provides
+
+  - ``program_config(config)``: the program's configuration, checked
+    against every published key that shapes the computation, refusing
+    what the program cannot compute;
+  - ``weight_specs(config)``: [(name, shape, law)] of every weight, the
+    names the program's parameter paths (``chipbench.weights``);
+  - ``step_terms(config)``: the fields of ``chipbench.counts.Terms``;
+  - ``logits_at(weights, config, tokens, positions, control=False)``: the
+    plain forward that decides ``correct`` (``chipbench.check``);
 * a traffic mix: ``traffic/<traffic>.json``;
 * a cell's geometry, load and check: ``workloads/<cell>.json``;
 * a per-layer metric: its reader, ``metrics/<metric>.py``, a module with
@@ -54,8 +65,7 @@ class Cell:
     bench_dir: Path
 
     def reference(self):
-        ref = self.config["reference"]
-        return load_module(self.bench_dir / "references" / f"{ref}.py")
+        return reference(self.config, self.bench_dir)
 
     def reader(self, metric: str):
         return load_module(self.bench_dir / "metrics" / f"{metric}.py").read
@@ -99,44 +109,12 @@ def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> dict:
     return table[device_kind]
 
 
-def program_config(config: dict):
-    """The program's ModelConfig for a configuration file, checked against
-    every published key that shapes what the model computes."""
-    from repro.configs.base import ModelConfig
-    cfg = ModelConfig(**config["program"])
-    d = config["hidden_size"]
-    qwen2 = config["model_type"] == "qwen2"
-    want = {
-        "family": "dense",
-        "d_model": d, "n_layers": config["num_hidden_layers"],
-        "n_heads": config["num_attention_heads"],
-        "n_kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim") or d // config[
-            "num_attention_heads"],
-        "d_ff": config["intermediate_size"],
-        "vocab_size": config["vocab_size"],
-        "rope_theta": config["rope_theta"],
-        "norm_eps": config["rms_norm_eps"],
-        "tie_embeddings": config["tie_word_embeddings"],
-        "act": config["hidden_act"],
-        # Qwen2 has a bias on Q, K and V (and none on the output)
-        "qkv_bias": qwen2,
-        "sliding_window": None,
-    }
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items()
-           if getattr(cfg, k) != v}
-    # what the program cannot compute at all
-    for key, ok in (("attention_bias", False), ("mlp_bias", False),
-                    ("rope_scaling", None)):
-        if config.get(key, ok) != ok:
-            bad[key] = ("not served by the program", config[key])
-    if config.get("sliding_window") is not None \
-            and config.get("use_sliding_window", True):
-        bad["sliding_window"] = ("not served by the program",
-                                 config["sliding_window"])
-    if config["model_type"] not in ("llama", "mistral", "qwen2"):
-        bad["model_type"] = ("llama, mistral or qwen2", config["model_type"])
-    if bad:
-        raise ValueError(f"{config['name']}: program config disagrees with "
-                         f"the published keys (program, file): {bad}")
-    return cfg
+def reference(config: dict, bench_dir: Path = BENCH_DIR):
+    """The configuration's reference module, ``references/<reference>.py``."""
+    return load_module(bench_dir / "references" / f"{config['reference']}.py")
+
+
+def program_config(config: dict, bench_dir: Path = BENCH_DIR):
+    """The program's configuration for a configuration file, as the
+    configuration's reference checks it against the published keys."""
+    return reference(config, bench_dir).program_config(config)

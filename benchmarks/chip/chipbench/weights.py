@@ -6,12 +6,18 @@ to the served dtype.  The same seed gives the same bits on any backend, so
 the reference can rebuild the weights itself after the served program's
 state is freed.
 
-Laws by ``kind`` (the reference module's ``weight_specs``):
+Laws (the third field of each of the reference module's ``weight_specs``):
 
 * ``dense``: std 1/sqrt(fan_in), fan_in the second-to-last axis;
 * ``embed``: std 1/sqrt(d_model), so tied logits are O(1);
 * ``norm``: 1 + uniform of std 0.1;
-* ``bias``: std 0.1.
+* ``bias``: std 0.1;
+* ``("uniform", mean, std)``: any other uniform law, such as that of a
+  recurrence's decay or step bias.
+
+The element counter has 32 bits: a weight of ``COUNTER_LIMIT`` elements or
+more is drawn slice by slice along its leading axis, slice ``i`` hashed
+under the name ``<name>/<i>``.
 """
 from __future__ import annotations
 
@@ -24,6 +30,7 @@ import numpy as np
 from jax import lax
 
 _SQRT3 = math.sqrt(3.0)
+COUNTER_LIMIT = 2 ** 32
 
 
 def seed32(seed: int) -> np.uint32:
@@ -44,8 +51,6 @@ def _fmix32(h):
 def _unit(seed, salt: int, shape):
     """Uniform values of mean 0 and std 1, [prod(shape)] hashed bits."""
     n = int(np.prod(shape))
-    if n >= 2 ** 32:
-        raise ValueError(f"weight of {n} elements exceeds the 32-bit counter")
     i = lax.iota(jnp.uint32, n)
     h = _fmix32(i * jnp.uint32(0x9E3779B1) + (seed ^ jnp.uint32(salt)))
     h = _fmix32(h ^ seed)
@@ -53,19 +58,35 @@ def _unit(seed, salt: int, shape):
     return ((2.0 * u - 1.0) * _SQRT3).reshape(shape)
 
 
-def _leaf(seed, name, shape, kind, d_model, dtype):
-    u = _unit(seed, zlib.crc32(name.encode()), shape)
+def _law(kind, shape, d_model):
+    """The map from unit values to the weight's law."""
     if kind == "dense":
-        x = u / math.sqrt(shape[-2])
-    elif kind == "embed":
-        x = u / math.sqrt(d_model)
-    elif kind == "norm":
-        x = 1.0 + 0.1 * u
-    elif kind == "bias":
-        x = 0.1 * u
-    else:
-        raise ValueError(f"unknown weight kind {kind!r} for {name}")
-    return x.astype(dtype)
+        return lambda u: u / math.sqrt(shape[-2])
+    if kind == "embed":
+        return lambda u: u / math.sqrt(d_model)
+    if kind == "norm":
+        return lambda u: 1.0 + 0.1 * u
+    if kind == "bias":
+        return lambda u: 0.1 * u
+    if isinstance(kind, (tuple, list)) and len(kind) == 3 \
+            and kind[0] == "uniform":
+        _, mean, std = kind
+        return lambda u: mean + std * u
+    raise ValueError(f"unknown weight law {kind!r}")
+
+
+def _draw(seed, name, shape, law, dtype):
+    if int(np.prod(shape)) < COUNTER_LIMIT:
+        return law(_unit(seed, zlib.crc32(name.encode()), shape)).astype(
+            dtype)
+    if len(shape) < 2:
+        raise ValueError(f"{name}: {shape} exceeds the 32-bit counter")
+    return jnp.stack([_draw(seed, f"{name}/{i}", shape[1:], law, dtype)
+                      for i in range(shape[0])])
+
+
+def _leaf(seed, name, shape, kind, d_model, dtype):
+    return _draw(seed, name, tuple(shape), _law(kind, shape, d_model), dtype)
 
 
 class Builder:

@@ -9,5 +9,5 @@ def read(r):
     busy = sum(r.device_s(s) for s in steps)
     if not steps or busy <= 0:
         return None
-    need = sum(counts.decode_step_bytes(r.hf, s.decodes) for s in steps)
+    need = sum(counts.decode_step_bytes(r.terms, s.decodes) for s in steps)
     return 100.0 * need / (busy * r.peaks["hbm_bytes_per_s"] * r.chips)

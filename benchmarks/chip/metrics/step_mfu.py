@@ -8,5 +8,5 @@ def read(r):
     busy = sum(r.device_s(s) for s in steps)
     if not steps or busy <= 0:
         return None
-    flops = sum(counts.step_flops(r.hf, s.chunks, s.decodes) for s in steps)
+    flops = sum(counts.step_flops(r.terms, s.chunks, s.decodes) for s in steps)
     return 100.0 * flops / (busy * r.peaks["bf16_flops_per_s"] * r.chips)

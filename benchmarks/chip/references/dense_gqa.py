@@ -29,7 +29,11 @@ benchmark's check has to reject.
 
 Weight names are the paths of the served program's parameter tree
 (``groups/0/...`` holds the layers stacked on a leading axis); nothing of
-the program is imported here.
+the program is imported here.  Beside the forward the module states what
+the benchmark needs to know of this architecture: the program's
+configuration for a configuration file (``program_config``, the one place
+that names the program's config class), the weights and their laws
+(``weight_specs``), and what a step costs (``step_terms``).
 """
 from __future__ import annotations
 
@@ -76,6 +80,74 @@ def weight_specs(hf: dict):
     if not hf["tie_word_embeddings"]:
         specs.append(("unembed", (d, v), "dense"))
     return specs
+
+
+def program_config(hf: dict):
+    """The program's ModelConfig for a configuration file, checked against
+    every published key that shapes what the model computes."""
+    from repro.configs.base import ModelConfig
+    cfg = ModelConfig(**hf["program"])
+    d = hf["hidden_size"]
+    qwen2 = hf["model_type"] == "qwen2"
+    want = {
+        "family": "dense",
+        "d_model": d, "n_layers": hf["num_hidden_layers"],
+        "n_heads": hf["num_attention_heads"],
+        "n_kv_heads": hf["num_key_value_heads"],
+        "head_dim": hf.get("head_dim") or d // hf["num_attention_heads"],
+        "d_ff": hf["intermediate_size"],
+        "vocab_size": hf["vocab_size"],
+        "rope_theta": hf["rope_theta"],
+        "norm_eps": hf["rms_norm_eps"],
+        "tie_embeddings": hf["tie_word_embeddings"],
+        "act": hf["hidden_act"],
+        # Qwen2 has a bias on Q, K and V (and none on the output)
+        "qkv_bias": qwen2,
+        "sliding_window": None,
+    }
+    bad = {k: (getattr(cfg, k), v) for k, v in want.items()
+           if getattr(cfg, k) != v}
+    # what the program cannot compute at all
+    for key, ok in (("attention_bias", False), ("mlp_bias", False),
+                    ("rope_scaling", None)):
+        if hf.get(key, ok) != ok:
+            bad[key] = ("not served by the program", hf[key])
+    if hf.get("sliding_window") is not None \
+            and hf.get("use_sliding_window", True):
+        bad["sliding_window"] = ("not served by the program",
+                                 hf["sliding_window"])
+    if hf["model_type"] not in ("llama", "mistral", "qwen2"):
+        bad["model_type"] = ("llama, mistral or qwen2", hf["model_type"])
+    if bad:
+        raise ValueError(f"{hf['name']}: program config disagrees with "
+                         f"the published keys (program, file): {bad}")
+    return cfg
+
+
+def step_terms(hf: dict) -> dict:
+    """``chipbench.counts.Terms`` of this model served in bf16: every
+    layer's projections and gated FFN per token, 4 * head_dim operations
+    per query head and (query, key) pair, the unembedding of one sampled
+    row, every weight read once (embedding rows gathered for the step's
+    tokens left out, a few KB), and K and V of one position in every
+    layer."""
+    d = hf["hidden_size"]
+    nh = hf["num_attention_heads"]
+    nkv = hf["num_key_value_heads"]
+    hd = hf.get("head_dim") or d // nh
+    ff = hf["intermediate_size"]
+    L = hf["num_hidden_layers"]
+    v = hf["vocab_size"]
+    proj = d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 3 * d * ff
+    per_layer = proj + 2 * d
+    if qkv_bias(hf):
+        per_layer += nh * hd + 2 * nkv * hd
+    return {"linear_flops_per_token": 2 * proj * L,
+            "attn_flops_per_pair": 4 * nh * hd * L,
+            "unembed_flops": 2 * d * v,
+            "weight_bytes": 2 * (per_layer * L + d + d * v),
+            "state_bytes_per_token": 2 * 2 * nkv * hd * L,
+            "state_bytes_per_request": 0}
 
 
 def qkv_bias(hf: dict) -> bool:

@@ -12,7 +12,9 @@ clock, measures ``--seconds`` seconds after a warm-up, checks the served
 tokens against the configuration's plain reference, and prints one JSON
 object as its last line: the cell's end-to-end metrics with ``--trace 0``,
 its per-layer metrics, read from a profiler trace of the whole window,
-with ``--trace 1``.  Progress and the numbers compared with their
+with ``--trace 1``.  A traced run compiles every program afresh, without
+the persistent compilation cache, so that the step's named scopes are
+the ones its operations carry.  Progress and the numbers compared with their
 limits go to standard error.
 
 ``--control 1`` puts the check's control (the reference one precision step
@@ -58,10 +60,16 @@ def main(argv=None) -> int:
               f"found {len(devices)} {devices[0].platform} device(s)",
               file=sys.stderr)
         return 2
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    # every program, however quick to compile, comes from the cache
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if args.trace:
+        # the persistent cache's key leaves metadata out: a step cached
+        # from code without the named scopes would run, and be read,
+        # without them
+        jax.config.update("jax_enable_compilation_cache", False)
+    else:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        # every program, however quick to compile, comes from the cache
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from chipbench import harness
     out = harness.run(cell, args.seed, args.seconds, bool(args.trace),
                       t_proc=T_PROC, devices=devices[:cell.chips],

@@ -6,11 +6,16 @@ in one process and report what each sustains.
         --rates 2,3,4,5 --seconds 20
 
 For each rate, on a server built afresh (from the process's compiled
-programs), after ``warmup_s`` of that rate's traffic, it prints one
-JSON line: requests completed per second, output tokens per second, TTFT
-p50/p90 and the backlog left when the window closes.  A rate the system
-sustains completes requests at the rate offered and leaves a backlog near
-its steady state; above the knee the backlog grows through the window.
+programs), after the cell's ``warmup_s`` of that rate's traffic, it
+prints one JSON line: requests completed per second, output tokens per
+second (and those of requests due before the window opened) beside those
+that the requests due in the window ask for, TTFT p50/p90, TBT
+p50/p90/p99, and the backlog (requests waiting or running) when the
+window opens and every 30 s after.  A rate the system sustains
+completes requests and tokens at the rate offered and keeps its backlog
+near its steady state; above the knee the backlog grows through the
+window.  Windows of some minutes hold some hundreds of requests, so one
+burst of the schedule does not decide a rate.
 The benchmark's cells run at fixed rates found this way once; the runs of
 ``run.py`` never search for one.
 """
@@ -47,6 +52,7 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    import numpy as np
     from chipbench import harness, measure, traffic
     from chipbench.driver import Driver, clock
     warm = cell.cell["warmup_s"]
@@ -58,21 +64,31 @@ def main(argv=None) -> int:
         t0 = clock()
         w0, w1 = t0 + warm, t0 + warm + args.seconds
         drv.open_loop(jobs, t0)
-        drv.run(t_end=w1)
+        backlogs = []
+        for at in [*np.arange(w0, w1, 30.0), w1]:
+            drv.run(t_end=at)
+            backlogs.append(len(srv.scheduler.waiting)
+                            + len(srv.scheduler.running))
         done = [g for g in drv.logs.values()
                 if g.finished is not None and w0 <= g.finished <= w1]
         t = measure.ttfts(drv.logs, w0, w1)
         g = measure.tbts(drv.logs, w0, w1)
-        waiting = len(srv.scheduler.waiting)
-        backlog = waiting + len(srv.scheduler.running)
+        due = measure.due_in(drv.logs, w0, w1)
+        early = {k: g for k, g in drv.logs.items() if g.due < w0}
         print(json.dumps({
             "rate": rate, "done_per_s": len(done) / args.seconds,
             "out_tok_s": measure.out_tokens(drv.logs, w0, w1) / args.seconds,
+            "of_warmup_out_tok_s": measure.out_tokens(early, w0, w1)
+            / args.seconds,
+            "due_out_tok_s": sum(x.job.max_new_tokens for x in due)
+            / args.seconds,
             "ttft_p50_ms": traffic.percentile(t, 50) * 1e3,
             "ttft_p90_ms": traffic.percentile(t, 90) * 1e3,
             "tbt_p50_ms": traffic.percentile(g, 50) * 1e3,
+            "tbt_p90_ms": traffic.percentile(g, 90) * 1e3,
             "tbt_p99_ms": traffic.percentile(g, 99) * 1e3,
-            "backlog": backlog, "waiting": waiting,
+            "backlog_every_30s": backlogs,
+            "waiting": len(srv.scheduler.waiting),
             "preemptions": drv.n_preemptions,
             "steps": sum(1 for s in drv.steps if w0 <= s.plan[0] < w1)}),
             flush=True)

@@ -8,22 +8,73 @@ import chipbench_tiny as T
 import numpy as np
 import pytest
 
+from chipbench import measure
 from chipbench import program as pm
+from chipbench import spec
 from chipbench import trace as tm
 
 MS = 1_000_000        # ns
+# the named scopes the program puts on its packed step
+STEP_SCOPES = ("embed", "qkv", "kv_write", "kv_read", "attn", "o_proj",
+               "ffn", "kv_carry", "unembed", "sample")
+KV_SCOPES = ("kv_read", "kv_write", "kv_carry")
+kv_move_share = spec.load_module(T.BENCH / "metrics" / "kv_move_share.py")
+
+
+def _kv_share(prog, red):
+    """The ``kv_move_share`` reader on what a traced run gives it."""
+    return kv_move_share.read(measure.Reading(
+        hf={}, terms=None, decode_lanes=1, chips=1, peaks={}, steps=[],
+        traced=red, scope_s=pm.device_by_scope(prog, red.window)))
 
 
 def test_scope_of_takes_the_innermost_scope_of_the_path():
+    """An op's scope path ends in its innermost scope and keeps the outer
+    ones before it."""
     body = "jit(_step_impl)/while/body/closed_call"
-    assert pm.scope_of(f"{body}/attn/while/body/dot_general") == "attn"
-    assert pm.scope_of(f"{body}/kv_carry/dynamic_update_slice") == \
+    sc = STEP_SCOPES
+    assert pm.scope_path(f"{body}/attn/while/body/dot_general", sc) == \
+        "attn"
+    assert pm.scope_path(f"{body}/kv_carry/dynamic_update_slice", sc) == \
         "kv_carry"
-    assert pm.scope_of("jit(f)/attn/x/kv_read/gather") == "kv_read"
-    assert pm.scope_of("ffn/reduce_sum") == "ffn"
-    assert pm.scope_of("jit(f)/qkvx/dot") == ""
-    assert pm.scope_of("%fusion.2 = bf16[4,8]") == ""
-    assert pm.scope_of("") == ""
+    assert pm.scope_path("jit(f)/attn/x/kv_read/gather", sc) == \
+        "attn/kv_read"
+    assert pm.scope_path("ffn/reduce_sum", sc) == "ffn"
+    assert pm.scope_path("jit(f)/qkvx/dot", sc) == ""
+    assert pm.scope_path("%fusion.2 = bf16[4,8]", sc) == ""
+    assert pm.scope_path("", sc) == ""
+    # any scope the caller names, not a fixed list
+    assert pm.scope_path("jit(f)/ffn/experts/dot", ("ffn", "experts")) == \
+        "ffn/experts"
+
+
+def test_named_scopes_of_a_traced_function():
+    """Every ``jax.named_scope`` of a function, through a scan's body, a
+    nested jit, a branch and a remat; no name JAX adds itself."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    @jax.jit
+    def inner(x):
+        with jax.named_scope("ssm_scan"):
+            return jnp.sin(x)
+
+    def f(x):
+        with jax.named_scope("outer"):
+            def body(c, y):
+                with jax.named_scope("experts"):
+                    return c + inner(y), None
+            c, _ = lax.scan(body, x[0], x)
+            c = jax.checkpoint(jnp.cos)(c)
+            with jax.named_scope("gate"):
+                c = jnp.einsum("i,i->i", c, c)
+                return lax.cond(c.sum() > 0, lambda z: z * 2,
+                                lambda z: z, c)
+
+    jaxpr = jax.jit(f).trace(jnp.ones((3, 4))).jaxpr.jaxpr
+    assert pm.named_scopes(jaxpr) == {"outer", "experts", "ssm_scan",
+                                      "gate"}
 
 
 def _bench():
@@ -88,14 +139,57 @@ def test_labels_sums_and_readings_on_a_synthetic_trace():
     # 8 + (18 - 16) + 8 + (18 - 10) = 26 ms of the 80 ms window
     assert pm.idle_on_host_share(prog, red) == pytest.approx(32.5)
     # 8 ms under kv_read of 26 ms busy
-    assert pm.kv_move_share(prog, red) == pytest.approx(100 * 8 / 26)
+    assert _kv_share(prog, red) == pytest.approx(100 * 8 / 26)
+    assert pm.op_times(prog, red.window) == pytest.approx([
+        ("all-reduce.3", "", 0.010), ("fusion.1", "ffn", 0.010),
+        ("fusion.1", "kv_read", 0.008)])
 
 
 def test_kv_move_share_counts_every_kv_scope():
     tr = _bench()
     prog = _program(tr, scopes=("kv_write", "kv_carry", "attn"))
     red = tm.reduce(tr)
-    assert pm.kv_move_share(prog, red) == pytest.approx(100 * 18 / 26)
+    assert _kv_share(prog, red) == pytest.approx(100 * 18 / 26)
+
+
+def test_a_scope_nested_under_a_kv_scope_still_counts_as_kv():
+    """A scope the program adds inside ``kv_read`` (a kernel's own, or a
+    finer span) keeps ``kv_read`` on its ops' paths: ``kv_move_share``
+    reads the same share with the inner scope as without it."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(x, inner):
+        with jax.named_scope("kv_read"):
+            y = jnp.sin(x)
+            if inner:
+                with jax.named_scope("unfold_rows"):
+                    y = y @ y
+            else:
+                y = y @ y
+        with jax.named_scope("ffn"):
+            return jnp.tanh(y) * 3.0
+
+    shares, paths = [], []
+    for inner in (False, True):
+        traced = jax.jit(step, static_argnums=1).trace(
+            jnp.ones((8, 8), jnp.float32), inner)
+        names = pm.named_scopes(traced.jaxpr.jaxpr)
+        sc = pm.hlo_scopes([traced.lower().compile().as_text()], names)
+        paths.append(set(sc.values()))
+        ops = [(i * 10 * MS, (i * 10 + 4) * MS, f"{lab} = x", p)
+               for i, (lab, p) in enumerate(sorted(sc.items()))]
+        prog = pm.Program(device={"/device:TPU:0": ops})
+        red = SimpleNamespace(window=(0, 10 * MS * len(ops)),
+                              busy_ns=4 * MS * len(ops),
+                              busy_s=4e-3 * len(ops))
+        n_kv = sum(1 for *_, p in ops if "kv_read" in p.split("/"))
+        assert n_kv >= 1
+        shares.append(_kv_share(prog, red))
+        assert shares[-1] == pytest.approx(100 * n_kv / len(ops))
+    assert "kv_read/unfold_rows" in paths[1]
+    assert "kv_read/unfold_rows" not in paths[0]
+    assert shares[1] == pytest.approx(shares[0])
 
 
 def test_queue_wait_counts_the_unscheduled_with_their_wait_so_far():
@@ -154,7 +248,7 @@ def test_without_program_spans_or_scopes_the_old_labels_stand(seed):
     assert set(pm.device_by_scope(prog, red.window)) == {"none"}
     assert pm.pack_ms_per_step(tr, prog, red.window) is None
     assert pm.idle_on_host_share(prog, red) is None
-    assert pm.kv_move_share(prog, red) is None
+    assert _kv_share(prog, red) is None
 
 
 # ------------------------------------------------- spans of the program
@@ -298,34 +392,55 @@ def test_scopes_from_compiled_hlo_text():
     none, and a label two modules scope apart is left out.  A device
     event's name (the op's HLO text, layouts and all) reads the same
     label."""
-    sc = pm.hlo_scopes([_HLO])
+    sc = pm.hlo_scopes([_HLO], STEP_SCOPES)
     assert sc == {"%sin.0 = f32[8,8]": "qkv", "%fusion.1 = f32[8,8]": "qkv",
                   "%dot.2 = f32[8,8]": "kv_carry"}
     event = ("%fusion.1 = f32[8,8]{1,0:T(8,128)} fusion(bf16[8,8]{1,0} "
              "%x), kind=kLoop, calls=%fused_computation.1")
     assert sc[tm.op_label(event)] == "qkv"
     other = _HLO.replace("jit(f)/while/body/kv_carry", "jit(f)/ffn")
-    assert pm.hlo_scopes([_HLO, other]) == {"%sin.0 = f32[8,8]": "qkv",
-                                            "%fusion.1 = f32[8,8]": "qkv"}
+    assert pm.hlo_scopes([_HLO, other], STEP_SCOPES) == {
+        "%sin.0 = f32[8,8]": "qkv", "%fusion.1 = f32[8,8]": "qkv"}
     unscoped = _HLO.replace(', metadata={op_name="jit(f)/while/body/'
                             'kv_carry/dot_general"}', "")
-    assert "%dot.2 = f32[8,8]" not in pm.hlo_scopes([_HLO, unscoped])
+    assert "%dot.2 = f32[8,8]" not in pm.hlo_scopes([_HLO, unscoped],
+                                                    STEP_SCOPES)
+    # a scope outside the list is not read
+    assert pm.hlo_scopes([_HLO], ("qkv",)) == {
+        "%sin.0 = f32[8,8]": "qkv", "%fusion.1 = f32[8,8]": "qkv"}
 
 
-def test_compiled_step_scopes_cover_the_kv_movement():
-    """On the tiny cell's compiled packed step, the scopes read from the
-    HLO text name the pool gathers, scatters and the layer scan's carry."""
+def _tiny_engine():
     import jax
     import jax.numpy as jnp
-    from chipbench import spec
     from repro.core.engine import Engine
     from repro.models import build_model
     cfg = spec.program_config(T.config())
     params = build_model(cfg).init_params(jax.random.PRNGKey(0),
                                           jnp.bfloat16)
-    eng = Engine(cfg, params, n_slots=4, max_len=128, chunk_size=32,
-                 decode_slots=3, dtype=jnp.bfloat16, paged=True,
-                 block_size=16)
-    sc = pm.hlo_scopes(pm.step_hlo(eng))
-    assert set(pm.KV_SCOPES) <= set(sc.values())
+    return Engine(cfg, params, n_slots=4, max_len=128, chunk_size=32,
+                  decode_slots=3, dtype=jnp.bfloat16, paged=True,
+                  block_size=16)
+
+
+def test_compiled_step_scopes_cover_the_kv_movement():
+    """On the tiny cell's compiled packed step, the scopes read from the
+    HLO text name the pool gathers, scatters and the layer scan's carry."""
+    sc = pm.step_scopes(_tiny_engine())
+    assert set(KV_SCOPES) <= set(sc.values())
     assert {"qkv", "ffn", "attn", "unembed"} <= set(sc.values())
+
+
+def test_step_scopes_read_as_the_fixed_list_did():
+    """The scopes found on the packed step's jaxpr are the ten the
+    program puts there, so every operation keeps the scope the fixed list
+    gave it, and every reading by scope stays as it was."""
+    eng = _tiny_engine()
+    names, texts = set(), []
+    for pad_chunk in (True, False):
+        pk = eng._pack(None, [], pad_chunk=pad_chunk)
+        traced = eng._step.trace(eng.params, pk, eng.cache, eng._key)
+        names |= pm.named_scopes(traced.jaxpr.jaxpr)
+        texts.append(traced.lower().compile().as_text())
+    assert names == set(STEP_SCOPES)
+    assert pm.step_scopes(eng) == pm.hlo_scopes(texts, STEP_SCOPES)

@@ -77,11 +77,15 @@ def _reading(tr, hf):
                   chunks=[(0, 256, True)], decodes=[100, 200]),
              Step(1, (0.04, 0.04), (0.04, 0.06), (0.06, 0.06),
                   chunks=[], decodes=[300, 400, 500])]
-    return measure.Reading(hf=hf, decode_lanes=4, chips=1,
+    return measure.Reading(hf=hf, terms=_terms(hf), decode_lanes=4, chips=1,
                            peaks={"bf16_flops_per_s": 1e14,
                                   "hbm_bytes_per_s": 1e12},
                            steps=steps, traced=red,
                            spans=tm.step_spans(tr))
+
+
+def _terms(hf):
+    return counts.Terms(**spec.reference(hf).step_terms(hf))
 
 
 def _metric(name):
@@ -100,11 +104,12 @@ def test_readers_on_synthetic_trace():
     assert _metric("engine_host_ms_per_step")(r) == pytest.approx(7.0)
     # step 0 has both host spans (10 + 10 ms); step 1 has neither
     assert _metric("sched_ms_per_step")(r) == pytest.approx(20.0)
-    flops = (counts.step_flops(hf, [(0, 256, True)], [100, 200])
-             + counts.step_flops(hf, [], [300, 400, 500]))
+    t = _terms(hf)
+    flops = (counts.step_flops(t, [(0, 256, True)], [100, 200])
+             + counts.step_flops(t, [], [300, 400, 500]))
     assert _metric("step_mfu")(r) == pytest.approx(
         100 * flops / (0.026 * 1e14))
-    need = counts.decode_step_bytes(hf, [300, 400, 500])
+    need = counts.decode_step_bytes(t, [300, 400, 500])
     assert _metric("step_hbm_share.decode")(r) == pytest.approx(
         100 * need / (0.010 * 1e12))
 
@@ -113,7 +118,8 @@ def test_readers_find_nothing_without_a_trace():
     r = _reading(_synthetic(), T.config())
     r.traced, r.spans = None, {}
     for name in ("device_idle_share", "engine_host_ms_per_step",
-                 "sched_ms_per_step", "step_mfu", "step_hbm_share.decode"):
+                 "sched_ms_per_step", "step_mfu", "step_hbm_share.decode",
+                 "kv_move_share"):
         assert _metric(name)(r) is None, name
     r.steps = []
     assert _metric("decode_lanes_used")(r) is None
