@@ -13,7 +13,8 @@ tokens, 32 output tokens each) go through ``OnlineServer`` with the
 run, once per paged attention backend (``xla``, then ``pallas``) in this
 one process.  For one prompt the packed step's prefill logits are checked
 against the model's plain batched forward in float32 at ``highest``
-matmul precision.
+matmul precision.  Every projection carries its published bias, drawn
+nonzero, so the check sees one the served path drops.
 
 ``--chips 4`` runs only the tensor-parallel phase: the same config served
 at ``tp=4`` on both backends, its prefill logits checked against ``tp=1``
@@ -52,8 +53,12 @@ from repro.scheduler import Request  # noqa: E402
 from repro.serving import OnlineServer  # noqa: E402
 
 ARCH = "granite-8b"
+# Granite-8B-Code's biases: Q, K, V and O (``attention_bias``), gate, up
+# and down (``mlp_bias``)
+BIASES = ("bq", "bk", "bv", "bo", "b_gate", "b_up", "b_down")
 # 36 bf16 layers are 36 x 436 MB = 15.7 GB of weights alone; 16 layers
-# (7.0 GB) plus embeddings (0.8 GB) and the KV pool (4.3 GB) fit 16 GB
+# (7.0 GB) plus the tied embedding (0.4 GB) and the KV pool (4.3 GB) fit
+# 16 GB
 DEPTH = 16
 
 
@@ -101,10 +106,21 @@ def smoke_config(depth: int = DEPTH):
 
 
 def init_params(cfg, seed: int, dtype=jnp.bfloat16, out_shardings=None):
-    """Random weights from ``seed``, generated on the device under jit."""
+    """Random weights from ``seed``, generated on the device under jit.
+    The model's init leaves every projection bias at zero; here each is
+    drawn with std 0.1, so the logit checks see a bias that is dropped."""
     model = build_model(cfg)
-    fn = jax.jit(lambda key: model.init_params(key, dtype),
-                 out_shardings=out_shardings)
+
+    def make(key):
+        params = model.init_params(key, dtype)
+        leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+        keys = jax.random.split(jax.random.fold_in(key, 1), len(leaves))
+        return jax.tree_util.tree_unflatten(tree, [
+            (0.1 * jax.random.normal(k, a.shape)).astype(a.dtype)
+            if getattr(p[-1], "key", None) in BIASES else a
+            for k, (p, a) in zip(keys, leaves)])
+
+    fn = jax.jit(make, out_shardings=out_shardings)
     return jax.block_until_ready(fn(jax.random.PRNGKey(seed)))
 
 

@@ -41,6 +41,9 @@ class ModelConfig:
     # --- attention details -------------------------------------------------
     head_dim: int = 0                # 0 -> d_model // n_heads
     qkv_bias: bool = False           # Qwen2-style bias on Q/K/V projections
+    o_bias: bool = False             # bias on the output projection
+                                     # (Llama ``attention_bias``: Q/K/V and O)
+    mlp_bias: bool = False           # bias on the gated FFN's gate/up/down
     rope_theta: float = 10_000.0
     sliding_window: Optional[int] = None   # SWA variant (sub-quadratic dense)
 
@@ -86,6 +89,15 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
         if self.family == "hybrid" and self.lru_width == 0:
             object.__setattr__(self, "lru_width", self.d_model)
+        # the gated silu FFN of dense-style layers holds the biases;
+        # elsewhere (an MLP, an MoE's experts, a state-space or hybrid
+        # stack) the key would be ignored or left out of param_count
+        if self.mlp_bias and (self.act != "silu" or self.family
+                              not in ("dense", "vlm", "encdec")):
+            raise ValueError(
+                f"mlp_bias is served on the gated silu FFN of dense, vlm "
+                f"and encdec layers only, not act={self.act!r} "
+                f"family={self.family!r}")
 
     # --- derived sizes -------------------------------------------------------
     @property
@@ -161,7 +173,11 @@ class ModelConfig:
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
+        if self.o_bias:
+            attn += d
         ffn_dense = 3 * d * f if self.act == "silu" else 2 * d * f
+        if self.mlp_bias:
+            ffn_dense += 2 * f + d
         for i in range(self.n_layers):
             kind = self._layer_kind(i)
             if kind == "rglru":

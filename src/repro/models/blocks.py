@@ -53,6 +53,8 @@ def init_attention(cfg: ModelConfig, key, dtype) -> Dict:
         p["bq"] = jnp.zeros((qd,), dtype)
         p["bk"] = jnp.zeros((kvd,), dtype)
         p["bv"] = jnp.zeros((kvd,), dtype)
+    if cfg.o_bias:
+        p["bo"] = jnp.zeros((d,), dtype)
     return p
 
 
@@ -68,6 +70,15 @@ def _qkv(cfg, p, x):
     k = k.reshape(*x.shape[:-1], cfg.n_kv_heads, hd)
     v = v.reshape(*x.shape[:-1], cfg.n_kv_heads, hd)
     return q, k, v
+
+
+def out_proj(p, out):
+    """Attention heads ``out`` [..., q_dim] back to the model width:
+    ``out Wo`` (+ ``bo`` where the parameters hold it).  Under tensor
+    parallelism ``Wo`` is row-parallel and ``bo`` replicated, so the bias
+    is added once, to the reduced sum."""
+    y = out @ p["wo"]
+    return y + p["bo"] if "bo" in p else y
 
 
 def init_attn_cache(cfg: ModelConfig, rows: int, max_len: int, dtype) -> Dict:
@@ -174,8 +185,7 @@ def attn_batched(cfg, p, x, cache, start, *, train: bool,
         out = cm.blocked_gqa_attention(q, ck, cv, pos)
         new_cache = {"k": ck, "v": cv}
 
-    out = out.reshape(B, L, cfg.q_dim) @ p["wo"]
-    return out, new_cache
+    return out_proj(p, out.reshape(B, L, cfg.q_dim)), new_cache
 
 
 def cross_batched(cfg, p, x, cache, *, memory=None):
@@ -196,7 +206,7 @@ def cross_batched(cfg, p, x, cache, *, memory=None):
     F = k.shape[1]
     mask = jnp.ones((B, L, F), bool)
     out = cm.gqa_attention(q, k, v, mask)
-    return out.reshape(B, L, cfg.q_dim) @ p["wo"], new_cache
+    return out_proj(p, out.reshape(B, L, cfg.q_dim)), new_cache
 
 
 # ------------------------------------------------------------ packed: attn
@@ -444,7 +454,7 @@ def attn_packed(cfg, p, x, cache, pk: PackedBatch,
         assert window is None, "window caches are slot-indexed, not paged"
         out, new_cache = _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk)
         with jax.named_scope("o_proj"):
-            return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
+            return out_proj(p, out.reshape(C + D, cfg.q_dim)), new_cache
 
     outs = []
     if window is None:
@@ -517,7 +527,7 @@ def attn_packed(cfg, p, x, cache, pk: PackedBatch,
 
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
     with jax.named_scope("o_proj"):
-        return out.reshape(C + D, cfg.q_dim) @ p["wo"], new_cache
+        return out_proj(p, out.reshape(C + D, cfg.q_dim)), new_cache
 
 
 def cross_packed(cfg, p, x, cache, pk: PackedBatch):
@@ -540,7 +550,7 @@ def cross_packed(cfg, p, x, cache, pk: PackedBatch):
         mask = jnp.ones((D, 1, F), bool)
         outs.append(cm.gqa_attention(q[C:, None], gk, gv, mask)[:, 0])
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-    return out.reshape(C + D, cfg.q_dim) @ p["wo"], cache
+    return out_proj(p, out.reshape(C + D, cfg.q_dim)), cache
 
 
 def compute_cross_kv(cfg, p, memory):

@@ -295,19 +295,33 @@ def write_ring(cache, cache_pos, new, new_pos, start_slot_axis=None):
 # --------------------------------------------------------------------------
 # feed-forward networks
 # --------------------------------------------------------------------------
-def init_glu_ffn(key, d_model: int, d_ff: int, dtype):
+def init_glu_ffn(key, d_model: int, d_ff: int, dtype, bias: bool = False):
     k1, k2, k3 = jax.random.split(key, 3)
-    return {
+    p = {
         "w_gate": dense_init(k1, (d_model, d_ff), dtype),
         "w_up": dense_init(k2, (d_model, d_ff), dtype),
         "w_down": dense_init(k3, (d_ff, d_model), dtype),
     }
+    if bias:
+        p["b_gate"] = jnp.zeros((d_ff,), dtype)
+        p["b_up"] = jnp.zeros((d_ff,), dtype)
+        p["b_down"] = jnp.zeros((d_model,), dtype)
+    return p
 
 
 def glu_ffn(p, x, act: str = "silu"):
+    """``(act(x Wg + bg) * (x Wu + bu)) Wd + bd``; the biases only where
+    the parameters hold them (``init_glu_ffn(bias=True)``)."""
     a = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
-    h = a(x @ p["w_gate"]) * (x @ p["w_up"])
-    return h @ p["w_down"]
+    g = x @ p["w_gate"]
+    if "b_gate" in p:
+        g = g + p["b_gate"]
+    g = a(g)
+    u = x @ p["w_up"]
+    if "b_up" in p:
+        u = u + p["b_up"]
+    y = (g * u) @ p["w_down"]
+    return y + p["b_down"] if "b_down" in p else y
 
 
 def init_mlp_ffn(key, d_model: int, d_ff: int, dtype):

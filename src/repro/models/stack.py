@@ -105,7 +105,8 @@ def init_layer(cfg: ModelConfig, kind: str, key, dtype) -> Dict:
     p["ln2"] = jnp.ones((d,), dtype)
     fs = _ffn_spec(cfg, kind)
     if fs == "glu":
-        p["ffn"] = cm.init_glu_ffn(ks[1], d, cfg.d_ff, dtype)
+        p["ffn"] = cm.init_glu_ffn(ks[1], d, cfg.d_ff, dtype,
+                                   bias=cfg.mlp_bias)
     elif fs == "mlp":
         p["ffn"] = cm.init_mlp_ffn(ks[1], d, cfg.d_ff, dtype)
     elif fs == "moe":

@@ -145,9 +145,12 @@ def param_pspecs(cfg: ModelConfig, shapes, *, mesh=None,
         if name in ("w_down", "w2"):                       # [f, d]
             return spec(_axis(div(shp[-2]), MDL),
                         _axis(fsdp and div(shp[-1], data_axis), DATA))
-        if name == "b1":
+        # a column-parallel matmul's bias splits with its columns; a
+        # row-parallel one's is replicated, so it is added once, to the
+        # reduced sum, and not once per shard
+        if name in ("b1", "b_gate", "b_up"):
             return spec(_axis(div(shp[-1]), MDL))
-        if name == "b2":
+        if name in ("b2", "b_down"):
             return spec(None)
         # ---- attention ----------------------------------------------------
         if name == "wq":
@@ -161,6 +164,8 @@ def param_pspecs(cfg: ModelConfig, shapes, *, mesh=None,
                         _axis(fsdp and div(shp[-1], data_axis), DATA))
         if name in ("bq", "bk", "bv"):
             return spec(_axis(div(shp[-1]), MDL))
+        if name == "bo":
+            return spec(None)
         # ---- SSD ----------------------------------------------------------
         if name in ("w_z", "w_x"):                         # [d, di]
             return spec(None, _axis(div(shp[-1]), MDL))
