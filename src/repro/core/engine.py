@@ -52,7 +52,7 @@ from repro.cache import BlockManager
 from repro.configs.base import ModelConfig
 from repro.core.sampling import SamplingParams, sample
 from repro.models import PackedBatch, build_model
-from repro.models.blocks import POOL_KEY
+from repro.models.blocks import POOL_KEY, kv_read_tiles
 from repro.models.registry import Model
 
 # paged block-pool leaves (repro.models.blocks.init_paged_attn_cache) are
@@ -344,6 +344,10 @@ class Engine:
         self._gather_pool = jax.jit(_gather_pool)
         self._scatter_pool = jax.jit(_scatter_pool, donate_argnums=(0,))
         self.iterations = 0
+        # per paged read of the packed steps executed, [tiles its key loop
+        # walked, tiles a read of the whole table walks]: how often the
+        # bound on the longest live context engages
+        self.kv_read_tiles = {"chunk": [0, 0], "decode": [0, 0]}
         # [1, V] logits of the last packed step's final valid chunk row
         # (what the sampler saw; None after a decode-only step) — left on
         # device, read only by correctness checks against a plain forward
@@ -616,9 +620,29 @@ class Engine:
         out: Dict[int, int] = {}
         chunks: List[Optional[ChunkWork]] = list(plan.chunks) or [None]
         for i, chunk in enumerate(chunks):
-            out.update(self._execute_packed(
-                chunk, plan.decodes if i == 0 else []))
+            decodes = plan.decodes if i == 0 else []
+            self._count_kv_read(chunk, decodes)
+            out.update(self._execute_packed(chunk, decodes))
         return out
+
+    def _count_kv_read(self, chunk: Optional[ChunkWork],
+                       decodes: Sequence[DecodeWork]):
+        """Add one packed step's paged reads to :attr:`kv_read_tiles`,
+        from the bounds the step computes on the device: the chunk's
+        ``start + len`` and the decode lanes' ``max(ctx) + 1`` (padding
+        lanes have context 0)."""
+        if not self.paged:
+            return
+        M, bs = self.blocks_per_seq, self.block_manager.block_size
+        reads = {}
+        if chunk is not None:
+            reads["chunk"] = chunk.start + len(chunk.tokens)
+        if self._lane_D:
+            reads["decode"] = max((w.ctx for w in decodes), default=0) + 1
+        for name, n_keys in reads.items():
+            tiles = self.kv_read_tiles[name]
+            tiles[0] += kv_read_tiles(n_keys, M, bs)
+            tiles[1] += kv_read_tiles(M * bs, M, bs)
 
     def warmup(self):
         """Compile both packed-step shapes — the hybrid ``(C, D)`` step (on

@@ -95,8 +95,9 @@ _register(
     name="REPRO_PAGED_ATTN_BACKEND", type=str, default="xla",
     choices=("xla", "pallas"),
     doc="Attention backend for the paged packed path: portable XLA "
-        "gather + blocked flash attention, or the block-table Pallas "
-        "kernels (native on TPU, interpret mode elsewhere).")
+        "key loop over the block tables, bounded by the longest live "
+        "context, or the block-table Pallas kernels (native on TPU, "
+        "interpret mode elsewhere).")
 _register(
     name="REPRO_PALLAS_INTERPRET", type=str, default="auto",
     choices=("0", "1", "false", "true", "auto"),

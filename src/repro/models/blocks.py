@@ -229,8 +229,8 @@ def set_paged_attn_mesh(mesh) -> None:
 
 
 def _paged_attn_backend() -> str:
-    """Attention backend for the paged packed path: "xla" (portable gather
-    + blocked flash attention, the default) or "pallas" (the block-table
+    """Attention backend for the paged packed path: "xla" (portable
+    :func:`paged_attention`, the default) or "pallas" (the block-table
     scalar-prefetch kernels of repro.kernels — native on TPU, interpret
     mode elsewhere).  Unrecognized values raise (in the registry's typed
     read) instead of silently falling through to xla."""
@@ -291,15 +291,14 @@ class LayerPool:
     def block_size(self, hd: int) -> int:
         return self.pool.shape[-2] * self.pool.shape[-1] // hd
 
+    def stored(self, idx):
+        """Blocks ``idx`` of this layer as stored, ``[*idx.shape, nk, 2,
+        r, w]`` (:func:`paged_block_shape`): one gather, no re-layout."""
+        return self.pool[self.base + idx]
+
     def blocks(self, idx, hd: int):
         """Blocks ``idx`` of this layer, ``[*idx.shape, nk, 2, bs, hd]``."""
-        return unfold_blocks(self.pool[self.base + idx], hd)
-
-    def rows(self, tables, hd: int):
-        """Per-token rows through block ``tables`` [..., M] ->
-        ``[..., M * bs, nk, 2, hd]``, as :func:`common.gather_block_rows`
-        reads a layer's own pool."""
-        return cm.block_rows(self.blocks(jnp.asarray(tables, jnp.int32), hd))
+        return unfold_blocks(self.stored(idx), hd)
 
     def whole(self, hd: int):
         """This layer's pool ``[N, nk, 2, bs, hd]`` (the Pallas kernels'
@@ -383,15 +382,105 @@ def _write_decodes(ref: LayerPool, pk: PackedBatch, kv) -> LayerPool:
     return ref.write_rows(phys, lanes, pk.decode_ctx % bs, kv)
 
 
+# blocks of a table per step of the paged read's key loop (chip sweep in
+# PERF.md: 16 / 32 / 64 blocks of 16 tokens)
+KV_TILE = 32
+
+
+def kv_read_tiles(n_keys, n_blocks: int, block_size: int):
+    """Steps the paged read's key loop takes over keys ``[0, n_keys)``
+    through a table ``n_blocks`` wide: one per tile of ``min(KV_TILE,
+    n_blocks)`` blocks that holds a key, and at least one.  ``n_keys``
+    is the step's traced bound in :func:`paged_attention` or a host
+    integer in the engine's counter, so the two agree by construction;
+    ``n_keys = n_blocks * block_size`` gives a whole table's count."""
+    span = min(KV_TILE, n_blocks) * block_size
+    n = (n_keys + span - 1) // span
+    return n + (n < 1)
+
+
+def paged_attention(q, ref: LayerPool, tables, q_pos, n_keys):
+    """Flash GQA of queries ``q`` [B, Lq, nq, hd] at positions ``q_pos``
+    [B, Lq] over the keys their block ``tables`` [B, M] name in the
+    layer's pool ``ref``; key ``j`` is visible to a query at ``i`` iff
+    ``j <= i``.
+
+    The key loop walks the tables tile by tile (:data:`KV_TILE` blocks)
+    and stops after the tile holding key ``n_keys - 1``
+    (:func:`kv_read_tiles`; a traced trip count, so one compiled program
+    serves every context).  A caller gives ``n_keys`` past the last key a
+    valid query can see, so the tiles skipped hold only keys the causal
+    mask drops; a query past ``n_keys`` (a padded chunk row) sees only
+    the keys walked.  Each step gathers its ``[B, tile]`` blocks whole
+    and as stored (on a TPU v5e one gather of K and V together ran about
+    15% faster than one of each), and folds them into an online softmax
+    (``m``, ``l``, ``acc`` in float32): no array spans a whole table.
+    Folded pools (``hd`` = 64, :func:`paged_block_shape`) are scored in
+    their 128-lane rows: copy ``p`` of each query holds it on lanes
+    ``[p*hd, (p+1)*hd)`` and zeros elsewhere, so it scores token ``p`` of
+    each lane row."""
+    B, Lq, nq, hd = q.shape
+    M = tables.shape[-1]
+    nk, _, R, W = ref.pool.shape[1:]
+    g, f = nq // nk, W // hd
+    bs, tb = R * f, min(KV_TILE, M)
+    n_tiles = kv_read_tiles(n_keys, M, bs)
+    if M % tb:                      # whole tiles; the tail names block 0
+        tables = jnp.pad(tables, ((0, 0), (0, -M % tb)))
+    lanes = jnp.eye(f, dtype=q.dtype)[:, :, None]          # [f, f, 1]
+    qf = (lanes * q.reshape(B, Lq, nk, g, 1, 1, hd)).reshape(
+        B, Lq, nk, g, f, W)
+    # key offset within a tile of each score [p, t, r]: block t, row r
+    tok = (jnp.arange(tb, dtype=jnp.int32)[:, None] * bs
+           + jnp.arange(R, dtype=jnp.int32) * f
+           + jnp.arange(f, dtype=jnp.int32)[:, None, None])
+    scale = 1.0 / math.sqrt(hd)
+
+    def tile(i, carry):
+        m, l, acc = carry                    # [B,nk,Lq,g], same, [..,f,W]
+        with jax.named_scope("kv_read"):
+            idx = jax.lax.dynamic_slice_in_dim(tables, i * tb, tb, axis=1)
+            kv = ref.stored(idx)                     # [B, tb, nk, 2, R, W]
+            k, v = kv[:, :, :, 0], kv[:, :, :, 1]
+        with jax.named_scope("attn"):
+            s = jnp.einsum("bqkgpw,btkrw->bkqgptr", qf, k,
+                           preferred_element_type=jnp.float32) * scale
+            valid = (i * tb * bs + tok <= q_pos[:, :, None, None, None]
+                     )[:, None, :, None]             # [B,1,Lq,1,f,tb,R]
+            s = jnp.where(valid, s, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=(-3, -2, -1)))
+            e = jnp.where(valid, jnp.exp(s - m_new[..., None, None, None]),
+                          0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + e.sum(axis=(-3, -2, -1))
+            acc = acc * alpha[..., None, None] + jnp.einsum(
+                "bkqgptr,btkrw->bkqgpw", e.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((B, nk, Lq, g), -1e30, jnp.float32)
+    l0 = jnp.zeros((B, nk, Lq, g), jnp.float32)
+    a0 = jnp.zeros((B, nk, Lq, g, f, W), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, tile, (m0, l0, a0))
+    with jax.named_scope("attn"):
+        # copy p's lanes [p*hd, (p+1)*hd) hold token p's weighted values
+        acc = (acc.reshape(acc.shape[:-1] + (f, hd)) * lanes).sum((-3, -2))
+        out = jnp.where(l[..., None] > 0,
+                        acc / jnp.maximum(l[..., None], 1e-30), 0.0)
+        return jnp.moveaxis(out, 1, 2).reshape(B, Lq, nq, hd).astype(q.dtype)
+
+
 def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
     """Block-table variant of the full-attention packed path.  The fused
     [nk, 2, hd] token rows are written first, the chunk's and then the
     decodes', by whole-block read-modify-writes; then the chunk and the
-    decodes read, either via a fused-row gather + K/V split (XLA backend)
-    or the fused-pool paged Pallas kernels.  Neither read can see the
-    other segment's writes except in the scratch block: a decode writes
-    only its own (copy-on-write forked) last block.  ``cache["pkv"]`` is
-    a :class:`LayerPool` and so is the result's."""
+    decodes read, either by :func:`paged_attention`, a key loop over
+    their tables bounded by the step's longest live context (the chunk's
+    ``chunk_start + chunk_len``, the decodes' ``max(decode_ctx) + 1``;
+    XLA backend), or by the fused-pool paged Pallas kernels.  Neither
+    read can see the other segment's writes except in the scratch block:
+    a decode writes only its own (copy-on-write forked) last block.
+    ``cache["pkv"]`` is a :class:`LayerPool` and so is the result's."""
     C, D = pk.num_chunk, pk.num_decode
     hd = cfg.head_dim
     ref = cache[POOL_KEY]
@@ -424,17 +513,13 @@ def _attn_packed_paged(cfg, p, q, k, v, pos, cache, pk: PackedBatch):
                                  pk.decode_ctx))
     else:
         if C:
-            with jax.named_scope("kv_read"):
-                row_k, row_v = cm.split_fused_kv(ref.rows(pk.chunk_blocks, hd))
-            with jax.named_scope("attn"):
-                outs.append(cm.blocked_gqa_attention(
-                    q[None, :C], row_k[None], row_v[None], pos[None, :C])[0])
+            outs.append(paged_attention(
+                q[None, :C], ref, pk.chunk_blocks[None], pos[None, :C],
+                pk.chunk_start + pk.chunk_len)[0])
         if D:
-            with jax.named_scope("kv_read"):
-                gk, gv = cm.split_fused_kv(ref.rows(pk.decode_blocks, hd))
-            with jax.named_scope("attn"):
-                outs.append(cm.blocked_gqa_attention(
-                    q[C:, None], gk, gv, pk.decode_ctx[:, None])[:, 0])
+            outs.append(paged_attention(
+                q[C:, None], ref, pk.decode_blocks, pk.decode_ctx[:, None],
+                jnp.max(pk.decode_ctx) + 1)[:, 0])
     out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
     return out, {POOL_KEY: ref}
 

@@ -108,8 +108,10 @@ def blocked_gqa_attention(q, k, v, q_pos, *, causal: bool = True,
                           qb: int = 128, kb: int = 4096):
     """Memory-efficient (flash-style) GQA in pure XLA: double scan over
     query and key blocks with an online softmax — O(qb*kb) live scores
-    instead of O(Lq*S).  This is the portable path the multi-pod dry-run
-    compiles; the Pallas kernels implement the same algorithm for TPU.
+    instead of O(Lq*S).  This is the portable path of the batched,
+    training and dense slot paths (the multi-pod dry-run compiles it); a
+    paged pool is read by ``blocks.paged_attention`` instead, the same
+    online softmax over tiles of its block tables.
 
     q     [B, Lq, nq, hd]
     k, v  [B, S, nk, hd]
@@ -240,14 +242,9 @@ def write_kv_slot(cache, new, slot, start):
 def gather_block_rows(pool, block_tables):
     """Fused paged pool -> dense per-token rows: pool [N, nk, 2, bs, hd]
     x tables [..., M] -> [..., M * bs, nk, 2, hd] in logical-position
-    order (shared by the packed paged attention path and the kernel
-    oracles); :func:`split_fused_kv` then yields the K and V rows."""
-    return block_rows(pool[jnp.asarray(block_tables, jnp.int32)])
-
-
-def block_rows(blocks):
-    """Gathered pool blocks [..., M, nk, 2, bs, hd] -> per-token rows
-    [..., M * bs, nk, 2, hd] in logical-position order."""
+    order (the kernel oracles' read); :func:`split_fused_kv` then yields
+    the K and V rows."""
+    blocks = pool[jnp.asarray(block_tables, jnp.int32)]
     rows = jnp.moveaxis(blocks, -2, -4)          # [..., M, bs, nk, 2, hd]
     return rows.reshape(rows.shape[:-5] + (rows.shape[-5] * rows.shape[-4],)
                         + rows.shape[-3:])

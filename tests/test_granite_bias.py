@@ -342,3 +342,41 @@ def test_every_bias_add_lies_under_its_projection_scope():
     for b, names in adds:
         path = program.scope_path(names, scopes).split("/")
         assert BIASES[b] in path, (b, names)
+
+
+def _loops(jaxpr):
+    """Every ``while`` body jaxpr in ``jaxpr`` or in a jaxpr nested in it."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "while":
+            found.append(e.params["body_jaxpr"].jaxpr)
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _loops(sub)
+    return found
+
+
+@pytest.mark.parametrize("pad_chunk", [True, False])
+def test_the_paged_read_is_scoped_inside_its_key_loop(pad_chunk):
+    """Both packed steps read the pool in key loops (one per read: the
+    chunk's and the decodes') whose bodies hold the gathers under
+    ``kv_read`` and the scores and softmax update under ``attn``, the
+    scopes the benchmark's readers find (``program.named_scopes``)."""
+    params = _params()
+    eng = Engine(CFG, params, n_slots=2, max_len=64, chunk_size=8,
+                 decode_slots=2, paged=True, block_size=8)
+    pk = eng._pack(None, [], pad_chunk=pad_chunk)
+    closed = jax.make_jaxpr(eng._step_impl)(params, pk, eng.cache, eng._key)
+    reads = [b for b in _loops(closed.jaxpr)
+             if any(e.primitive.name == "gather" for e in b.eqns)]
+    assert len(reads) == (2 if pad_chunk else 1)
+    for body in reads:
+        assert {"kv_read", "attn"} <= program.named_scopes(body)
+        for e in body.eqns:
+            names = [s.name for s in e.source_info.name_stack.stack]
+            if e.primitive.name == "gather":
+                assert "kv_read" in names
+            if e.primitive.name in ("dot_general", "exp"):
+                assert "attn" in names

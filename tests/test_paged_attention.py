@@ -233,3 +233,55 @@ def test_unaligned_final_chunk_padding_never_clobbers_context(paged):
                  **(dict(block_size=16) if paged else {}), **kw)
     # last chunk: start=56, padded window covers 56..87 > max_len=64
     assert run(eng, [(0, 28), (28, 56), (56, 62)]) == want
+
+
+def test_kv_read_tiles_count_each_steps_bounded_reads(monkeypatch):
+    """``Engine.kv_read_tiles`` adds, per packed step, the tiles each
+    read's key loop walks (keys up to the chunk's end, or up to the
+    longest decode context) beside a whole table's count; served prompts
+    of 3-37 tokens with 2-block tiles (16 keys) span 1-3 of the table's
+    4 tiles.  Warm-up counts nothing, and the step stays two programs."""
+    from repro.models import blocks as bk
+    monkeypatch.setattr(bk, "KV_TILE", 2)
+    cfg, model, params = cached_model("tinyllama-1.1b")
+    eng = Engine(cfg, params, n_slots=4, max_len=64, chunk_size=8,
+                 decode_slots=3, paged=True, block_size=8)
+    eng.warmup()
+    assert eng.kv_read_tiles == {"chunk": [0, 0], "decode": [0, 0]}
+    rng = np.random.default_rng(4)
+    prompts = {r: rng.integers(1, cfg.vocab_size, n).tolist()
+               for r, n in enumerate((3, 20, 37))}
+    want = {"chunk": [0, 0], "decode": [0, 0]}
+
+    def expect(read, n_keys):
+        want[read][0] += max(1, -(-n_keys // 16))
+        want[read][1] += 4
+
+    ctx, last, todo = {}, {}, []
+    for r, p in prompts.items():
+        eng.add_request(r)
+        todo += [(r, c) for c in plan_chunks(len(p), 8)]
+    while todo or ctx:
+        # two chunks in a plan while three or more wait: a second,
+        # chunk-only sub-step with an empty decode read
+        chunks = []
+        for r, c in todo[:2 if len(todo) > 2 else 1]:
+            chunks.append(ChunkWork(r, prompts[r][c.start:c.start + c.length],
+                                    c.start, c.is_last))
+        todo = todo[len(chunks):]
+        decodes = [DecodeWork(r, last[r], n) for r, n in ctx.items()]
+        for i, c in enumerate(chunks or [None]):
+            if c is not None:
+                expect("chunk", c.start + len(c.tokens))
+            expect("decode", max([d.ctx for d in decodes if i == 0],
+                                 default=0) + 1)
+        out = eng.execute(IterationPlan(chunks=chunks, decodes=decodes))
+        for r, tok in out.items():
+            last[r] = tok
+            ctx[r] = ctx.get(r, len(prompts[r]) - 1) + 1
+            if ctx[r] >= len(prompts[r]) + 4:
+                del ctx[r]
+                eng.release(r)
+    assert eng.kv_read_tiles == want
+    assert want["decode"][0] > want["chunk"][0] > 0
+    assert eng._step._cache_size() == 2

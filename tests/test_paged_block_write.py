@@ -15,8 +15,9 @@ import numpy as np
 import pytest
 from jax.tree_util import DictKey
 
+from repro.kernels.ref import (paged_chunked_prefill_attention_ref,
+                               paged_decode_attention_ref)
 from repro.models import blocks as bk
-from repro.models import common as cm
 from repro.models.packed import make_packed
 
 BS, M, N, NK = 16, 8, 40, 2           # max_len = M * BS = 128
@@ -78,11 +79,11 @@ DECODES = [(5, _table([25])), (16, _table([27, 29])),
 D = 5
 
 
-def _packed(case):
-    start, C, n, table = CASES[case]
+def _packed(case, chunk=None, decodes=DECODES):
+    start, C, n, table = chunk or CASES[case]
     ctx = np.zeros((D,), np.int32)
     tables = np.zeros((D, M), np.int32)
-    for i, (c, t) in enumerate(DECODES):
+    for i, (c, t) in enumerate(decodes):
         ctx[i], tables[i] = c, t
     return make_packed(
         chunk_tokens=np.zeros((C,), np.int32), chunk_start=start,
@@ -168,16 +169,89 @@ def test_block_write_into_the_stack_touches_one_layer(case, hd):
 
 @pytest.mark.parametrize("hd", [128, 64])
 def test_stacked_rows_gather_the_layers_blocks(hd):
-    """``LayerPool.rows`` reads the layer's blocks in the stack, as
-    ``gather_block_rows`` reads the layer's own pool; ``whole`` is that
-    pool."""
+    """``LayerPool.stored`` gathers the layer's blocks in the stack as
+    stored, and ``blocks`` unfolds them into the layer's own pool's
+    ``[bs, hd]`` pages; ``whole`` is that pool."""
     pk = _packed("mid_block")
     pool, _ = _kv_and_pool(pk, hd)
     stack = jnp.stack([pool * 2.0, pool])
     ref = _of_layer(stack, 1)
     logical = jnp.asarray(_logical(pool, hd))
-    np.testing.assert_array_equal(
-        np.asarray(ref.rows(pk.decode_blocks, hd)),
-        np.asarray(cm.gather_block_rows(logical, pk.decode_blocks)))
+    np.testing.assert_array_equal(np.asarray(ref.stored(pk.decode_blocks)),
+                                  np.asarray(pool[pk.decode_blocks]))
+    np.testing.assert_array_equal(np.asarray(ref.blocks(pk.decode_blocks, hd)),
+                                  np.asarray(logical[pk.decode_blocks]))
     np.testing.assert_array_equal(np.asarray(ref.whole(hd)),
                                   np.asarray(logical))
+
+
+# ------------------------------------------------------- the tiled read
+NQ = 4
+# (chunk_start, C, chunk_len, the chunk's table; decode lanes (ctx,
+# table), the rest padding).  The "cow_shared" decodes share the chunk's
+# prefix blocks 3 and 5, as a forked prefix leaves them
+READS = {
+    "ragged": ((40, 32, 32, _table([3, 5, 7, 9, 11])), DECODES),
+    # 2-block tiles: the chunk's keys end on one, the longest decode's
+    # last key opens the next
+    "tile_boundary": ((0, 32, 32, _table([3, 5])),
+                      [(32, _table([25, 27, 29])), (31, _table([31, 33]))]),
+    "max_len": ((96, 32, 32, _table([3, 5, 7, 9, 11, 13, 15, 17])),
+                [(127, _table([19, 21, 23, 25, 27, 29, 31, 33])),
+                 (32, _table([35, 36, 37]))]),
+    "cow_shared": ((32, 32, 8, _table([3, 5, 7])),
+                   [(40, _table([3, 5, 21])), (33, _table([3, 5, 23]))]),
+    "empty_chunk": ((0, 32, 0, _table([])), []),
+}
+
+
+def _read_case(case, hd, dtype):
+    """The case's step, a pool as stored and its q, k, v rows."""
+    pk = _packed(case, *READS[case])
+    C = pk.num_chunk
+    ks = jax.random.split(jax.random.PRNGKey(sorted(READS).index(case)), 4)
+    pool = jax.random.normal(
+        ks[0], (N,) + bk.paged_block_shape(NK, BS, hd)).astype(dtype)
+    q, k, v = (jax.random.normal(kk, (C + D, h, hd)).astype(dtype)
+               for kk, h in zip(ks[1:], (NQ, NK, NK)))
+    return pk, pool, q, k, v
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("tile", [2, 3, None])
+@pytest.mark.parametrize("hd", [128, 64])
+@pytest.mark.parametrize("case", sorted(READS))
+def test_tiled_read_matches_the_paged_oracles(case, hd, tile, dtype,
+                                              monkeypatch):
+    """The xla branch of the packed step, its key loop bounded by the
+    step's longest live context, reads what ``kernels/ref.py``'s paged
+    oracles read from the pool it wrote: every decode lane (padding lanes
+    at context 0 included) and every valid chunk row.  Tiles of 2 and 3
+    blocks (3 does not divide the table) walk 1-4 steps; the module's
+    tile spans the whole table.  The layer sits at its offset in a
+    stacked pool."""
+    if tile is not None:
+        monkeypatch.setattr(bk, "KV_TILE", tile)
+    pk, pool, q, k, v = _read_case(case, hd, dtype)
+    C = pk.num_chunk
+    stack = jnp.stack([pool * 2, pool])
+    cfg = type("Cfg", (), {"head_dim": hd})
+    out, cache = bk._attn_packed_paged(cfg, None, q, k, v, pk.positions(),
+                                       {bk.POOL_KEY: _of_layer(stack, 1)},
+                                       pk)
+    written = jnp.asarray(_logical(
+        bk.carry_out(stack, cache[bk.POOL_KEY], 1)[1], hd))
+    want_c = paged_chunked_prefill_attention_ref(
+        q[:C], written, pk.chunk_blocks, pk.chunk_start)
+    want_d = paged_decode_attention_ref(
+        q[C:], written, pk.decode_blocks, pk.decode_ctx)
+    # an empty chunk's rows all lie in the first tile, which every read
+    # walks: they read what the oracle reads too
+    rows = int(pk.chunk_len) or C
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got = np.asarray(out, np.float32)
+    np.testing.assert_allclose(got[:rows], np.asarray(want_c[:rows],
+                                                      np.float32),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(got[C:], np.asarray(want_d, np.float32),
+                               rtol=tol, atol=tol)

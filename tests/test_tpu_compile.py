@@ -146,19 +146,21 @@ def pool_shaped_movers(hlo: str, n_blocks, block_dims, n_layers) -> list:
     return out
 
 
-def _compiled_steps(topo, cfg, n_blocks):
+def _compiled_steps(topo, cfg, n_blocks, n_slots=8, max_len=1024):
     """Both packed steps (hybrid, decode-only) of an engine serving ``cfg``
-    (8 slots of up to 1,024 tokens, blocks of 16, a pool of ``n_blocks``),
-    compiled for one chip of the described v5e; and the pool's shape."""
+    (``n_slots`` slots, one a chunk's and the rest decode lanes, of up to
+    ``max_len`` tokens, blocks of 16, a pool of ``n_blocks``), compiled
+    for one chip of the described v5e; and the pool's shape."""
     from repro.core.engine import DecodeWork, Engine
     from repro.models import build_model
     model = build_model(cfg)
     params = jax.eval_shape(lambda: model.init_params(
         jax.random.PRNGKey(0), DTYPE))
-    eng = Engine(cfg, params, n_slots=8, max_len=1024, chunk_size=CHUNK,
-                 decode_slots=7, dtype=DTYPE, paged=True, block_size=16)
+    eng = Engine(cfg, params, n_slots=n_slots, max_len=max_len,
+                 chunk_size=CHUNK, decode_slots=n_slots - 1, dtype=DTYPE,
+                 paged=True, block_size=16)
     cache = jax.eval_shape(lambda: model.init_cache(
-        9, 1024, DTYPE, paged_blocks=n_blocks, block_size=16))
+        n_slots + 1, max_len, DTYPE, paged_blocks=n_blocks, block_size=16))
     eng.add_request(7)
     one = SingleDeviceSharding(topo.devices[0])
     steps = []
@@ -171,17 +173,28 @@ def _compiled_steps(topo, cfg, n_blocks):
     return steps, pool.shape
 
 
+def _mistral_widths():
+    """Mistral-7B's widths at 2 layers (``hd`` = 128)."""
+    from repro.configs import get_config
+    return dataclasses.replace(
+        get_config("tinyllama-1.1b"), n_layers=2, d_model=4096, n_heads=32,
+        n_kv_heads=8, head_dim=128, d_ff=14336, vocab_size=32768,
+        rope_theta=1e6)
+
+
+def _qwen2_widths():
+    """Qwen2-0.5B's widths at 2 layers (``hd`` = 64: a folded pool)."""
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("qwen2-0.5b"), n_layers=2,
+                               vocab_size=32768)
+
+
 def test_layer_scan_moves_no_whole_pool(topo):
     """Both packed steps of an engine at Mistral-7B widths (2 layers, 513
     blocks of 16) compile, for a v5e, with no copy, slice or write-back
     of a layer's pool or of the stacked pool: the scan hands each layer
     the stack and writes whole blocks at the layer's block offset."""
-    from repro.configs import get_config
-    cfg = dataclasses.replace(
-        get_config("tinyllama-1.1b"), n_layers=2, d_model=4096, n_heads=32,
-        n_kv_heads=8, head_dim=128, d_ff=14336, vocab_size=32768,
-        rope_theta=1e6)
-    steps, shape = _compiled_steps(topo, cfg, 513)
+    steps, shape = _compiled_steps(topo, _mistral_widths(), 513)
     assert shape == (2, 513, NK, 2, 16, HD)
     for step in steps:
         hlo = step.as_text()
@@ -197,9 +210,7 @@ def test_layer_scan_keeps_a_64_wide_pool_in_place(topo):
     page would have the compiler lay the stack out with its block axis
     minor and copy all of it into a padded layout and back each step
     (temporaries about twice the pool)."""
-    from repro.configs import get_config
-    cfg = dataclasses.replace(get_config("qwen2-0.5b"), n_layers=2,
-                              vocab_size=32768)
+    cfg = _qwen2_widths()
     assert cfg.head_dim == 64
     block = bk.paged_block_shape(cfg.n_kv_heads, 16, cfg.head_dim)
     assert block == (cfg.n_kv_heads, 2, 8, 128)
@@ -209,3 +220,49 @@ def test_layer_scan_keeps_a_64_wide_pool_in_place(topo):
     for step in steps:
         assert pool_shaped_movers(step.as_text(), 16385, block, 2) == []
         assert step.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
+# an instruction's name, element type and output shape in HLO text
+_TYPED = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]", re.M)
+
+
+def full_table_shaped(hlo: str, lanes: int, width: int, bs: int) -> list:
+    """Floating-point instructions shaped over ``lanes`` decode lanes'
+    whole block tables ``width`` blocks wide: blocks ``[D, M, ...]`` or
+    ``[D * M, ...]``, or token rows ``[D, M * bs, ...]`` (a leading unit
+    axis aside)."""
+    out = []
+    for name, dtype, shape in _TYPED.findall(hlo):
+        dims = [int(d) for d in shape.split(",") if d]
+        if dims[:1] == [1]:
+            dims = dims[1:]
+        if dtype in ("bf16", "f32") and (
+                (len(dims) >= 3 and dims[0] == lanes
+                 and dims[1] in (width, width * bs))
+                or (len(dims) >= 2 and dims[0] == lanes * width)):
+            out.append(f"{name} {dtype}[{shape}]")
+    return out
+
+
+# both steps' temporaries (hybrid, decode-only) when the read gathered
+# every decode lane's whole table and re-laid it out: the code before the
+# tiled read, compiled by ``_compiled_steps`` at these geometries
+_FULL_TABLE_TEMP = {"mistral": (475_048_960, 471_385_088),
+                    "qwen2": (401_043_968, 400_201_216)}
+
+
+@pytest.mark.parametrize("arch,widths,n_slots,max_len,n_blocks", [
+    ("mistral", _mistral_widths, 8, 8192, 513),
+    ("qwen2", _qwen2_widths, 64, 4096, 16385)])
+def test_paged_read_spans_no_whole_table(topo, arch, widths, n_slots,
+                                         max_len, n_blocks):
+    """At the cells' geometries (Mistral: 7 decode lanes of up to 8,192
+    tokens; Qwen2: 63 lanes of up to 4,096), neither packed step compiled
+    for a v5e holds a float array over the decode lanes' whole tables:
+    the read gathers one tile of table entries a loop step.  Each step's
+    temporaries stay under a tenth of what the whole-table read took."""
+    steps, _ = _compiled_steps(topo, widths(), n_blocks, n_slots, max_len)
+    for step, before in zip(steps, _FULL_TABLE_TEMP[arch]):
+        assert full_table_shaped(step.as_text(), n_slots - 1,
+                                 max_len // 16, 16) == []
+        assert step.memory_analysis().temp_size_in_bytes < before // 10
